@@ -886,6 +886,8 @@ def _compressed_wire_hlo(fast: bool):
     import sys
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # forced host devices only: the chip, if any, stays with the parent
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.join(os.path.dirname(__file__), "..")
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
@@ -1149,6 +1151,8 @@ def bench_sharded_comm(fast: bool):
     import sys
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # forced host devices only: the chip, if any, stays with the parent
+    env["JAX_PLATFORMS"] = "cpu"
     env["BENCH_FAST"] = "1" if fast else "0"
     root = os.path.join(os.path.dirname(__file__), "..")
     # repo root too: the script imports the timing helper from benchmarks.run

@@ -29,12 +29,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.analysis.rules import Finding
 
-#: jaxpr primitive names treated as collectives (both historical spellings
-#: of the scatter reduction, and ``psum2`` — what ``lax.psum`` rebinds to
-#: inside a ``check_rep=True`` shard_map after the replication rewrite)
-COLLECTIVE_PRIMS = {"psum", "psum2", "all_gather", "all_to_all", "ppermute",
-                    "psum_scatter", "reduce_scatter"}
-_CANON = {"reduce_scatter": "psum_scatter", "psum2": "psum"}
+#: jaxpr primitive names treated as collectives (both spellings of the
+#: scatter reduction, and ``psum_invariant`` — what ``lax.psum`` binds inside
+#: a ``check_vma=True`` shard_map, where its operand varies over the axis)
+COLLECTIVE_PRIMS = {"psum", "psum_invariant", "all_gather", "all_to_all",
+                    "ppermute", "psum_scatter", "reduce_scatter"}
+_CANON = {"reduce_scatter": "psum_scatter", "psum_invariant": "psum"}
 
 #: jax dtype name -> HLO dtype token (the hlo_stats keying)
 _HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "float16": "f16",

@@ -160,13 +160,16 @@ def build(experiment: Experiment) -> Run:
     eval_batch = jax.tree.map(lambda v: v[0],
                               batch_fn(jax.random.PRNGKey(123)))
 
-    def eval_fn(state) -> float:
+    @jax.jit
+    def eval_loss(state, val):
         s = views(state)
         p = (s.params if hasattr(s, "params")
              else {"body": s.x, "head": s.y})
         p0 = jax.tree.map(lambda v: v[0], p)
-        l, _ = model.loss(p0, eval_batch["val"])
-        return float(l)
+        return model.loss(p0, val)[0]
+
+    def eval_fn(state) -> float:
+        return float(eval_loss(state, eval_batch["val"]))
 
     return Run(spec=exp, init=init, step=step, views=views,
                shardings=shardings, eval_fn=eval_fn, batch_fn=batch_fn,

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.storm.kernel import _resolve_interpret
+
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -91,7 +93,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bh(q, k, v, *, causal: bool = True, window: int = 0,
                        softcap: float = 0.0, scale: float = None,
                        bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """q, k, v: [BH, S, D] (batch×heads flattened). S must divide by bq/bk."""
     BH, S, D = q.shape
     kv_len = k.shape[1]
@@ -119,5 +121,5 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running denom l
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(q, k, v)

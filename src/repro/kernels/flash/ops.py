@@ -14,7 +14,7 @@ from repro.kernels.flash.kernel import DEFAULT_BK, DEFAULT_BQ, flash_attention_b
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: float = None,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q, k, v: [B, S, H, D] (kv heads already repeated to H). Returns the
     attention output in the same layout."""
     B, S, H, D = q.shape

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.storm.kernel import _resolve_interpret
+
 TIME_TILE = 128
 CHAN_BLOCK = 512
 
@@ -46,7 +48,7 @@ def _lru_kernel(a_ref, b_ref, h0_ref, o_ref, state_ref, *, time_tile: int):
 
 @functools.partial(jax.jit, static_argnames=("time_tile", "interpret"))
 def lru_scan_padded(a, b, h0, *, time_tile: int = TIME_TILE,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """a, b: [B, S, C] fp32 (S divisible by time_tile); h0: [B, C].
     Returns h: [B, S, C] with h[:, t] = a[:,t]·h[:,t-1] + b[:,t], h[:,-1]=h0."""
     B, S, C = a.shape
@@ -67,5 +69,5 @@ def lru_scan_padded(a, b, h0, *, time_tile: int = TIME_TILE,
         out_specs=pl.BlockSpec((1, time_tile, cb), lambda bi, ci, ti: (bi, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, cb), jnp.float32)],
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(a, b, h0)

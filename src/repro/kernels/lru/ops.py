@@ -7,7 +7,7 @@ from repro.kernels.lru.kernel import TIME_TILE, lru_scan_padded
 
 
 def lru_scan(a, b, h0=None, *, time_tile: int = TIME_TILE,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """h_t = a_t·h_{t−1} + b_t along axis 1. a, b: [B, S, C] fp32.
 
     Pads S to the time tile (a=1, b=0 padding is a no-op on the state) and C

@@ -21,8 +21,8 @@ Two kernel families live here:
   update used by the flat-buffer substrate (``repro.optim.flat``). The three
   FedBiOAcc sequences x/ν, y/ω, u/q are laid out as contiguous,
   tile-aligned segments of ONE flat buffer; per-*block* (lr, decay) scalars
-  arrive through SMEM and are indexed with ``pl.program_id``, so a single
-  launch streams all three sequences with their own hyper-parameters.
+  arrive through SMEM, one pair per grid step, so a single launch streams
+  all three sequences with their own hyper-parameters.
   ``storm3_step_flat`` is the half-step variant (variable step + partial
   momentum ``decay·(m − g_old)``) used inside the real train step, where the
   new-iterate oracle — and hence ``g_new`` — only exists after the updated
@@ -35,9 +35,12 @@ Two kernel families live here:
   momentum stream).
 
 Layout: inputs are flattened to [N] and tiled as (BLOCK,) VMEM blocks on a 1D
-grid. Scalars (lr, decay — one pair, or one pair per block) arrive via
-scalar prefetch (SMEM). ``interpret`` defaults to auto: Pallas interpreter
-everywhere except on a real TPU backend.
+grid. Scalars (lr, decay) arrive in SMEM: the single pair whole, the
+per-block tables one entry per grid step (:func:`tile_scalars` — a
+``[T, 1, 1]`` view blocked as ``(None, 1, 1)``), so SMEM holds a few words
+whatever the buffer size; a whole ``[T]`` table outgrows the 1 MiB SMEM of
+a TPU v5e at published widths with small blocks. ``interpret`` defaults to
+auto: Pallas interpreter everywhere except on a real TPU backend.
 """
 from __future__ import annotations
 
@@ -57,6 +60,36 @@ def _resolve_interpret(interpret):
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
+
+
+#: a compiled kernel's 1-D blocks must cover whole tiles of the XLA layout
+#: of a 1-D array (1024 elements); smaller blocks fail inside Mosaic
+TPU_BLOCK_QUANTUM = 1024
+
+
+def _resolve_block_launch(interpret, block: int):
+    """:func:`_resolve_interpret` for a launch over ``block``-element tiles,
+    refusing up front a block a compiled TPU kernel cannot stream."""
+    flag = _resolve_interpret(interpret)
+    if not flag and block % TPU_BLOCK_QUANTUM:
+        raise ValueError(
+            f"block={block}: a compiled TPU kernel streams 1-D blocks of a "
+            f"multiple of {TPU_BLOCK_QUANTUM} elements (whole tiles of the "
+            f"1-D layout); set execution.storm_block to such a multiple")
+    return flag
+
+
+#: one SMEM scalar per grid step of a per-block table viewed as [T, 1, 1]
+#: (Mosaic refuses rank-1 blocks smaller than a 128-lane tile; a squeezed
+#: leading axis over trailing (1, 1) dims equal to the array's is accepted)
+TILE_SMEM = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+
+
+def tile_scalars(table):
+    """Per-block f32 table [T] → the [T, 1, 1] view whose entries
+    :data:`TILE_SMEM` streams one per grid step (read as ``ref[0, 0]``)."""
+    return table.astype(jnp.float32).reshape(-1, 1, 1)
 
 
 def _storm_kernel(scal_ref, p_ref, m_ref, gnew_ref, gold_ref,
@@ -102,9 +135,8 @@ def storm_update_flat(p, m, g_new, g_old, lr, decay, *,
 
 def _storm3_kernel(lrs_ref, decays_ref, p_ref, m_ref, gnew_ref, gold_ref,
                    pout_ref, mout_ref):
-    i = pl.program_id(0)
-    lr = lrs_ref[i]
-    decay = decays_ref[i]
+    lr = lrs_ref[0, 0]
+    decay = decays_ref[0, 0]
     m = m_ref[...].astype(jnp.float32)
     g_new = gnew_ref[...].astype(jnp.float32)
     g_old = gold_ref[...].astype(jnp.float32)
@@ -114,9 +146,8 @@ def _storm3_kernel(lrs_ref, decays_ref, p_ref, m_ref, gnew_ref, gold_ref,
 
 def _storm3_step_kernel(lrs_ref, decays_ref, p_ref, m_ref, gold_ref,
                         pout_ref, mout_ref):
-    i = pl.program_id(0)
-    lr = lrs_ref[i]
-    decay = decays_ref[i]
+    lr = lrs_ref[0, 0]
+    decay = decays_ref[0, 0]
     m = m_ref[...].astype(jnp.float32)
     g_old = gold_ref[...].astype(jnp.float32)
     pout_ref[...] = (p_ref[...].astype(jnp.float32) - lr * m).astype(pout_ref.dtype)
@@ -137,16 +168,15 @@ def storm3_update_flat(p, m, g_new, g_old, lrs, decays, *,
     grid = (n // block,)
     assert lrs.shape == decays.shape == grid, (lrs.shape, grid)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _storm3_kernel,
         grid=grid,
-        in_specs=[smem, smem, bspec, bspec, bspec, bspec],
+        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec, bspec],
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=_resolve_interpret(interpret),
-    )(lrs.astype(jnp.float32), decays.astype(jnp.float32), p, m, g_new, g_old)
+        interpret=_resolve_block_launch(interpret, block),
+    )(tile_scalars(lrs), tile_scalars(decays), p, m, g_new, g_old)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -163,16 +193,15 @@ def storm3_step_flat(p, m, g_old, lrs, decays, *,
     grid = (n // block,)
     assert lrs.shape == decays.shape == grid, (lrs.shape, grid)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _storm3_step_kernel,
         grid=grid,
-        in_specs=[smem, smem, bspec, bspec, bspec],
+        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec],
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=_resolve_interpret(interpret),
-    )(lrs.astype(jnp.float32), decays.astype(jnp.float32), p, m, g_old)
+        interpret=_resolve_block_launch(interpret, block),
+    )(tile_scalars(lrs), tile_scalars(decays), p, m, g_old)
 
 
 # ---------------------------------------------------------------------------
@@ -187,17 +216,15 @@ def storm3_step_flat(p, m, g_old, lrs, decays, *,
 
 def _momsgd3_kernel(lrs_ref, betas_ref, p_ref, m_ref, g_ref,
                     pout_ref, mout_ref):
-    i = pl.program_id(0)
-    lr = lrs_ref[i]
-    beta = betas_ref[i]
+    lr = lrs_ref[0, 0]
+    beta = betas_ref[0, 0]
     m_new = beta * m_ref[...].astype(jnp.float32) + g_ref[...].astype(jnp.float32)
     pout_ref[...] = (p_ref[...].astype(jnp.float32) - lr * m_new).astype(pout_ref.dtype)
     mout_ref[...] = m_new.astype(mout_ref.dtype)
 
 
 def _sgd3_kernel(lrs_ref, p_ref, g_ref, pout_ref):
-    i = pl.program_id(0)
-    lr = lrs_ref[i]
+    lr = lrs_ref[0, 0]
     pout_ref[...] = (p_ref[...].astype(jnp.float32)
                      - lr * g_ref[...].astype(jnp.float32)).astype(pout_ref.dtype)
 
@@ -213,15 +240,14 @@ def sgd3_step_flat(p, g, lrs, *,
     grid = (n // block,)
     assert lrs.shape == grid, (lrs.shape, grid)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _sgd3_kernel,
         grid=grid,
-        in_specs=[smem, bspec, bspec],
+        in_specs=[TILE_SMEM, bspec, bspec],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct((n,), p.dtype),
-        interpret=_resolve_interpret(interpret),
-    )(lrs.astype(jnp.float32), p, g)
+        interpret=_resolve_block_launch(interpret, block),
+    )(tile_scalars(lrs), p, g)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -237,16 +263,15 @@ def momsgd3_step_flat(p, m, g, lrs, betas, *,
     grid = (n // block,)
     assert lrs.shape == betas.shape == grid, (lrs.shape, grid)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _momsgd3_kernel,
         grid=grid,
-        in_specs=[smem, smem, bspec, bspec, bspec],
+        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec],
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=_resolve_interpret(interpret),
-    )(lrs.astype(jnp.float32), betas.astype(jnp.float32), p, m, g)
+        interpret=_resolve_block_launch(interpret, block),
+    )(tile_scalars(lrs), tile_scalars(betas), p, m, g)
 
 
 # ---------------------------------------------------------------------------

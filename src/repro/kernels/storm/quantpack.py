@@ -13,13 +13,15 @@ error is independent of how the buffer is partitioned.
 Layout contract (same as the storm3 kernels): flat [N] input with N a
 multiple of ``block``, 1-D grid of (block,) VMEM tiles.  ``quantpack_flat``
 emits the int8 payload plus the [N // block] scale vector; ``quantunpack_flat``
-consumes them (scales via SMEM, indexed by ``pl.program_id`` like the
-per-tile lr/decay tables).  The ``*_jnp`` lowerings delegate to
-``ref.quantpack_ref`` / ``ref.quantunpack_ref`` — the single source of the
-jnp math — and are bit-identical to the kernels (same ``jnp.round``
-half-to-even rounding, same ``where``-guarded zero-tile divisor), so the
-substrate can dispatch per backend (``_dispatch`` in ``optim/flat.py``)
-without changing a single communicated bit.
+consumes them.  Scales move one per grid step through SMEM in both
+directions (``kernel.TILE_SMEM``, like the per-tile lr/decay tables): a
+``(1,)`` VMEM block is below the 128-lane tile Mosaic accepts.  The
+``*_jnp`` lowerings delegate to ``ref.quantpack_ref`` /
+``ref.quantunpack_ref`` — the single source of the jnp math — and are
+bit-identical to the kernels (same ``jnp.round`` half-to-even rounding,
+same ``where``-guarded zero-tile divisor), so the substrate can dispatch
+per backend (``_dispatch`` in ``optim/flat.py``) without changing a single
+communicated bit.
 """
 from __future__ import annotations
 
@@ -28,9 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.storm.kernel import BLOCK, _resolve_interpret
+from repro.kernels.storm.kernel import (BLOCK, TILE_SMEM,
+                                        _resolve_block_launch, tile_scalars)
 
 
 def _quantpack_kernel(x_ref, q_ref, s_ref):
@@ -38,12 +40,11 @@ def _quantpack_kernel(x_ref, q_ref, s_ref):
     scale = jnp.max(jnp.abs(x)) / 127.0
     safe = jnp.where(scale > 0, scale, 1.0)
     q_ref[...] = jnp.clip(jnp.round(x / safe), -127.0, 127.0).astype(jnp.int8)
-    s_ref[0] = scale
+    s_ref[0, 0] = scale
 
 
 def _quantunpack_kernel(s_ref, q_ref, x_ref):
-    i = pl.program_id(0)
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[i]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -53,15 +54,16 @@ def quantpack_flat(x, *, block: int = BLOCK, interpret: bool | None = None):
     assert n % block == 0, (n, block)
     grid = (n // block,)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         _quantpack_kernel,
         grid=grid,
         in_specs=[bspec],
-        out_specs=[bspec, pl.BlockSpec((1,), lambda i: (i,))],
+        out_specs=[bspec, TILE_SMEM],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int8),
-                   jax.ShapeDtypeStruct((n // block,), jnp.float32)],
-        interpret=_resolve_interpret(interpret),
+                   jax.ShapeDtypeStruct((n // block, 1, 1), jnp.float32)],
+        interpret=_resolve_block_launch(interpret, block),
     )(x)
+    return q, s.reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -73,15 +75,14 @@ def quantunpack_flat(q, scales, *, block: int = BLOCK,
     assert scales.shape == (n // block,), (scales.shape, n, block)
     grid = (n // block,)
     bspec = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _quantunpack_kernel,
         grid=grid,
-        in_specs=[smem, bspec],
+        in_specs=[TILE_SMEM, bspec],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=_resolve_interpret(interpret),
-    )(scales.astype(jnp.float32), q)
+        interpret=_resolve_block_launch(interpret, block),
+    )(tile_scalars(scales), q)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))

@@ -49,6 +49,7 @@ from repro.api import Experiment, RollbackError, RollbackGuard, SpecError, \
 from repro.checkpoint import (checkpoint_metadata, load_checkpoint,
                               load_experiment, save_checkpoint)
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 
 # CLI dest → dotted Experiment path, for every flag that simply sets one
 # spec field.  Flags with coupled semantics (--seed, --participation
@@ -345,8 +346,11 @@ def main(argv=None):
     ap = _parser()
     ns = ap.parse_args(argv)
     if ns.max_restarts > 0:
+        # the parent only starts children: it never touches a backend, so
+        # each child is the one process holding the chip
         return _supervise(ns, list(argv) if argv is not None
                           else sys.argv[1:])
+    enable_compile_cache()
     # SUPPRESS-defaulted flags only exist on the namespace when passed
     driver = {"experiment", "resume", "ckpt_dir", "ckpt_every", "log_every",
               "max_restarts", "restart_backoff", "crash_at_step"}

@@ -84,7 +84,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.kernels.storm.kernel import (BLOCK, momsgd3_step_flat,
@@ -406,10 +405,10 @@ def _launch(mode, flag, shard, spec, grp, kern_pallas, kern_jnp,
         outs = outs if n_out > 1 else (outs,)
         return tuple(o.reshape(lshape) for o in outs)
 
-    return shard_map(body, mesh=shard.mesh,
-                     in_specs=(pb,) * (len(bufs) + len(tables)),
-                     out_specs=(pb,) * n_out,
-                     check_rep=False)(*bufs, *tables)
+    return jax.shard_map(body, mesh=shard.mesh,
+                         in_specs=(pb,) * (len(bufs) + len(tables)),
+                         out_specs=(pb,) * n_out,
+                         check_vma=False)(*bufs, *tables)
 
 
 def storm_partial_step(spec: FlatSpec, var_bufs, mom_bufs, g_old_bufs,
@@ -1318,9 +1317,9 @@ def _client_mean_masked_sharded(spec: FlatSpec, bufs, modes, num_groups,
         pw = PartitionSpec(shard.data_axis)
         ins = ((pb,) + ((pb,) if has_ef else ())
                + (pw,) * (len(ws) + len(cops)))
-        res = shard_map(body, mesh=shard.mesh, in_specs=ins,
-                        out_specs=((pb, pb) if has_ef else pb),
-                        check_rep=False)(
+        res = jax.shard_map(body, mesh=shard.mesh, in_specs=ins,
+                            out_specs=((pb, pb) if has_ef else pb),
+                            check_vma=False)(
             buf, *((ebuf,) if has_ef else ()), *ws, *cops)
         if has_ef:
             out.append(res[0])

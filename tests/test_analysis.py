@@ -13,7 +13,6 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import LINT_RULES, RULES
@@ -134,8 +133,8 @@ def int8_run():
 def _seed_psum(run, elems):
     """run with one extra psum of ``elems`` f32 smuggled into the step."""
     def bad_step(state, batch):
-        leak = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=run.mesh,
-                         in_specs=P(), out_specs=P())(
+        leak = jax.shard_map(lambda x: jax.lax.psum(x, "data"),
+                             mesh=run.mesh, in_specs=P(), out_specs=P())(
                              jnp.zeros((elems,), jnp.float32))
         s, aux = run.step(state, batch)
         z = (0 * leak.sum()).astype(jnp.float32)

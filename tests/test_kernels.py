@@ -141,6 +141,25 @@ def test_momsgd3_matches_ref_and_degenerates_to_sgd(dtype, rng):
                                           np.float32), rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("kernel", ["storm3_step", "quantpack"])
+def test_compiled_kernel_refuses_block_below_tile(kernel):
+    """A compiled launch over blocks that are not whole 1024-element tiles
+    is refused with a named bound before Mosaic sees it; the interpreter
+    (and hence every CPU test) still takes any block."""
+    from repro.kernels.storm.kernel import storm3_step_flat
+    from repro.kernels.storm.quantpack import quantpack_flat
+    block, n = 256, 256 * 4
+    x = jnp.ones((n,), jnp.float32)
+    t = jnp.ones((n // block,), jnp.float32)
+    call = {"storm3_step": lambda i: storm3_step_flat(
+                x, x, x, t, t, block=block, interpret=i),
+            "quantpack": lambda i: quantpack_flat(x, block=block,
+                                                  interpret=i)}[kernel]
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        call(False)
+    call(True)
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
@@ -161,7 +180,8 @@ def test_flash_vs_ref(case, dtype, rng):
     B, S, H, D, causal, window, cap = case
     ks = jax.random.split(rng, 3)
     q, k, v = (jax.random.normal(kk, (B, S, H, D)).astype(dtype) for kk in ks)
-    out = flash_attention(q, k, v, causal=causal, window=window, softcap=cap)
+    out = flash_attention(q, k, v, causal=causal, window=window, softcap=cap,
+                          interpret=True)
 
     def to_bh(x):
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, S, D)
@@ -205,9 +225,11 @@ def test_lru_vs_ref(shape, rng):
     a = jax.random.uniform(ks[0], shape, minval=0.7, maxval=0.999)
     b = 0.1 * jax.random.normal(ks[1], shape)
     h0 = jax.random.normal(ks[2], (B, C))
-    np.testing.assert_allclose(lru_scan(a, b, h0), lru_scan_ref(a, b, h0),
+    np.testing.assert_allclose(lru_scan(a, b, h0, interpret=True),
+                               lru_scan_ref(a, b, h0),
                                atol=2e-6, rtol=2e-5)
-    np.testing.assert_allclose(lru_scan(a, b), lru_scan_ref(a, b),
+    np.testing.assert_allclose(lru_scan(a, b, interpret=True),
+                               lru_scan_ref(a, b),
                                atol=2e-6, rtol=2e-5)
 
 
@@ -219,7 +241,7 @@ if st is not None:
         ks = jax.random.split(jax.random.PRNGKey(seed), 2)
         a = jax.random.uniform(ks[0], (B, S, C), minval=0.0, maxval=1.0)
         b = jax.random.normal(ks[1], (B, S, C))
-        got = lru_scan(a, b)
+        got = lru_scan(a, b, interpret=True)
         # sequential reference
         h = np.zeros((B, C), np.float32)
         want = np.zeros((B, S, C), np.float32)

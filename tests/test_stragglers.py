@@ -30,11 +30,24 @@ from repro.optim import sequences as seqs
 
 M = 4
 
-# seed=0 round 0 over M=4: times ~[0.37, 1.56, 0.79, 0.33] — client 1
-# misses a 1.0 deadline while quorum 0.25 holds without extensions
+# a heavy tail around a 1.0 deadline: round 0's seed-0 draws put some of
+# the M=4 clients past the deadline and the rest before it (the tests that
+# need a mixed round derive who is late from the draws — :func:`_late0`)
 _MIXED = StragglerSpec(base_time=1.0, tail=1.0, deadline=1.0, quorum=0.25,
                        max_extensions=0, adapt_rate=0.0, seed=0,
                        over_provision=0)
+
+
+def _late0(spec=_MIXED):
+    """[M] bool: the clients whose round-0 draw misses ``spec.deadline``,
+    asserted to make a mixed round — at least one late client, and enough
+    on-time ones for quorum without an extension."""
+    strag = make_stragglers(spec, M)
+    late = np.asarray(strag.round_times(0)) > spec.deadline
+    assert late.any(), "round 0 must have a late client"
+    assert (~late).sum() >= int(strag.quorum_count(jnp.ones(M))), \
+        "round 0's on-time clients must make quorum"
+    return late
 
 
 # ---------------------------------------------------------------------------
@@ -103,11 +116,13 @@ def test_round_decision_generous_deadline():
 
 
 def test_round_decision_mixed_round():
-    """The seed-0 round: client 1 misses the 1.0 deadline, quorum holds
-    without extensions, non-sampled clients never arrive."""
+    """The seed-0 round: exactly the clients whose draw misses the 1.0
+    deadline are late, quorum holds without extensions, non-sampled
+    clients never arrive."""
     strag = make_stragglers(_MIXED, M)
+    late = _late0()
     arr, eff, ext, _ = _decide(strag, 0, np.ones(M), 1.0)
-    np.testing.assert_array_equal(arr, [1.0, 0.0, 1.0, 1.0])
+    np.testing.assert_array_equal(arr, np.where(late, 0.0, 1.0))
     assert eff == 1.0 and ext == 0
     # a non-sampled client is not an arrival even if its draw is fast
     arr2, _, _, _ = _decide(strag, 0, [0.0, 1.0, 1.0, 1.0], 100.0)
@@ -292,10 +307,13 @@ def test_stragglers_off_is_the_synchronous_engine(algo):
 
 
 def test_late_policy_semantics_on_engine():
-    """One elastic round with a known miss (seed 0: client 1): `drop`
-    freezes the straggler's row bit-exact and ages its staleness, `carry`
-    lets the row advance locally while still aging, `cancel` freezes the
-    row and treats the client as served (no aging)."""
+    """One elastic round with known misses (the seed-0 draws past the
+    deadline, :func:`_late0`): `drop` freezes each straggler's row
+    bit-exact and ages its staleness, `carry` lets the rows advance
+    locally while still aging, `cancel` freezes the rows and treats the
+    clients as served (no aging)."""
+    late = _late0()
+    on_time = int(np.flatnonzero(~late)[0])
     states = {}
     for policy in ("drop", "carry", "cancel"):
         strag = make_stragglers(_MIXED._replace(late_policy=policy), M)
@@ -307,20 +325,23 @@ def test_late_policy_semantics_on_engine():
     for policy in ("drop", "cancel"):
         init_vars, s = states[policy]
         for v0, v1 in zip(init_vars, s.vars):
-            _bits(v0[1], v1[1])                    # straggler row frozen
-            assert not np.array_equal(np.asarray(v0[0]), np.asarray(v1[0]))
+            _bits(v0[late], v1[late])              # straggler rows frozen
+            assert not np.array_equal(np.asarray(v0[on_time]),
+                                      np.asarray(v1[on_time]))
     init_vars, s = states["carry"]
-    assert any(not np.array_equal(np.asarray(v0[1]), np.asarray(v1[1]))
-               for v0, v1 in zip(init_vars, s.vars))   # kept computing
+    for i in np.flatnonzero(late):                 # kept computing
+        assert any(not np.array_equal(np.asarray(v0[i]), np.asarray(v1[i]))
+                   for v0, v1 in zip(init_vars, s.vars))
     np.testing.assert_array_equal(np.asarray(states["drop"][1].stale),
-                                  [0, 1, 0, 0])
+                                  late.astype(int))
     np.testing.assert_array_equal(np.asarray(states["carry"][1].stale),
-                                  [0, 1, 0, 0])
+                                  late.astype(int))
     np.testing.assert_array_equal(np.asarray(states["cancel"][1].stale),
                                   [0, 0, 0, 0])
     # drop and cancel aggregate the same arrivals-only mean: the arrived
     # rows of the communicated section match bit-exactly
-    _bits(states["drop"][1].vars[0][0], states["cancel"][1].vars[0][0])
+    _bits(states["drop"][1].vars[0][on_time],
+          states["cancel"][1].vars[0][on_time])
 
 
 def test_deadline_rides_state_and_updates_at_comm_steps(tmp_path):
