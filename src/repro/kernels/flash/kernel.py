@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.storm.kernel import _resolve_interpret
+from repro.kernels.backend import resolve_interpret
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -121,5 +121,5 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running denom l
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.storm.kernel import _resolve_interpret
+from repro.kernels.backend import resolve_interpret
 
 TIME_TILE = 128
 CHAN_BLOCK = 512
@@ -69,5 +69,5 @@ def lru_scan_padded(a, b, h0, *, time_tile: int = TIME_TILE,
         out_specs=pl.BlockSpec((1, time_tile, cb), lambda bi, ci, ti: (bi, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, cb), jnp.float32)],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(a, b, h0)

@@ -51,15 +51,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 BLOCK = 64 * 1024   # elements per VMEM tile (bf16: 128 KiB/input, 4 inputs
                     # + 2 outputs ≈ 768 KiB of VMEM — comfortably under 16 MiB)
-
-
-def _resolve_interpret(interpret):
-    """interpret=None → auto: compile on TPU, interpret elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 #: a compiled kernel's 1-D blocks must cover whole tiles of the XLA layout
@@ -68,9 +63,9 @@ TPU_BLOCK_QUANTUM = 1024
 
 
 def _resolve_block_launch(interpret, block: int):
-    """:func:`_resolve_interpret` for a launch over ``block``-element tiles,
+    """:func:`resolve_interpret` for a launch over ``block``-element tiles,
     refusing up front a block a compiled TPU kernel cannot stream."""
-    flag = _resolve_interpret(interpret)
+    flag = resolve_interpret(interpret)
     if not flag and block % TPU_BLOCK_QUANTUM:
         raise ValueError(
             f"block={block}: a compiled TPU kernel streams 1-D blocks of a "
@@ -125,7 +120,7 @@ def storm_update_flat(p, m, g_new, g_old, lr, decay, *,
         out_specs=[block, block],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(scal, p, m, g_new, g_old)
 
 

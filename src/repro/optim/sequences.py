@@ -97,6 +97,7 @@ at non-communication steps the two schedules coincide exactly.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -781,16 +782,32 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
             lambda p: jax.sharding.NamedSharding(shard.mesh, p), pspecs,
             is_leaf=lambda p: isinstance(p, jax.sharding.PartitionSpec))
 
+    def _concrete_on_mesh(tree) -> bool:
+        # False for an abstract init (eval_shape): its caller places it
+        return shard is not None and not any(
+            isinstance(l, jax.core.Tracer) for l in jax.tree.leaves(tree))
+
     def _placed(state: FlatState) -> FlatState:
-        if shard is None or any(isinstance(l, jax.core.Tracer)
-                                for l in jax.tree.leaves(state)):
-            return state        # abstract init (eval_shape) — caller places
+        if not _concrete_on_mesh(state):
+            return state
         return jax.device_put(state, state_shardings(state))
+
+    def _flatten(trees, dtype=None):
+        """``flat.flatten_tree`` of [M, ...] trees, built in its shards on
+        a mesh: on one device the shard-major interleave is a relayout
+        whose TPU compile takes minutes at published widths."""
+        def fn(t):
+            return flat.flatten_tree(spec, t, batch_dims=1, dtype=dtype)
+        if not _concrete_on_mesh(trees):
+            return fn(trees)
+        on_mesh = functools.partial(jax.sharding.NamedSharding, shard.mesh)
+        trees = jax.device_put(
+            trees, on_mesh(jax.sharding.PartitionSpec(shard.data_axis)))
+        return jax.jit(fn, out_shardings=on_mesh(shard.buffer_spec))(trees)
 
     def init_state(var_trees, mom_trees=None, step=None, stale=None,
                    retry=None, ef=None, deadline=None):
-        vars_b = flat.flatten_tree(spec, {s: var_trees[s] for s in sections},
-                                   batch_dims=1)
+        vars_b = _flatten({s: var_trees[s] for s in sections})
         if not has_mom:
             mom_b = ()
         elif mom_trees is None:
@@ -800,10 +817,8 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
             # would largely destroy
             mom_b = tuple(jnp.zeros(b.shape, jnp.float32) for b in vars_b)
         else:
-            mom_b = flat.flatten_tree(
-                spec, {q.section: mom_trees[q.momentum]
-                       for q in aspec.sequences},
-                batch_dims=1, dtype=jnp.float32)
+            mom_b = _flatten({q.section: mom_trees[q.momentum]
+                              for q in aspec.sequences}, dtype=jnp.float32)
         if not need_stale:
             stale_b = ()
         elif stale is None:
