@@ -18,6 +18,11 @@ from typing import Callable
 import jax
 
 from repro.core.tree_util import tree_axpy, tree_scale, tree_sub, tree_vdot
+from repro.telemetry.trace import annotate
+
+#: named scope of every differentiation of the lower objective g (∇g and its
+#: Hessian-vector products): profiler/HLO metadata only
+G_SCOPE = "fed.oracle_g"
 
 
 def grad_x(f: Callable, x, y, batch):
@@ -30,12 +35,16 @@ def grad_y(f: Callable, x, y, batch):
 
 def hvp_yy(g: Callable, x, y, batch, u):
     """∇²_{yy} g(x, y; batch) · u via forward-over-reverse."""
-    return jax.jvp(lambda yy: jax.grad(g, argnums=1)(x, yy, batch), (y,), (u,))[1]
+    with annotate(G_SCOPE):
+        return jax.jvp(lambda yy: jax.grad(g, argnums=1)(x, yy, batch),
+                       (y,), (u,))[1]
 
 
 def jvp_xy(g: Callable, x, y, batch, u):
     """∇²_{xy} g(x, y; batch) · u  =  ∇_x ⟨∇_y g(x, y; batch), u⟩."""
-    return jax.grad(lambda xx: tree_vdot(jax.grad(g, argnums=1)(xx, y, batch), u))(x)
+    with annotate(G_SCOPE):
+        return jax.grad(lambda xx: tree_vdot(
+            jax.grad(g, argnums=1)(xx, y, batch), u))(x)
 
 
 def u_step(g: Callable, f: Callable, x, y, u, batch_g, batch_f, tau: float):
@@ -65,10 +74,11 @@ def _neumann_ihvp(g: Callable, x, y, batch_g, v0, q_terms: int, tau: float):
     local-lower oracles so the series semantics cannot diverge."""
     v = v0
     acc = v0
-    for _ in range(q_terms):
-        v = tree_axpy(-tau, hvp_yy(g, x, y, batch_g, v), v)   # v ← (I − τH) v
-        acc = jax.tree.map(lambda a, b: a + b, acc, v)
-    return tree_scale(tau, acc)
+    with annotate(G_SCOPE):
+        for _ in range(q_terms):
+            v = tree_axpy(-tau, hvp_yy(g, x, y, batch_g, v), v)  # (I − τH) v
+            acc = jax.tree.map(lambda a, b: a + b, acc, v)
+        return tree_scale(tau, acc)
 
 
 def neumann_hypergrad(g: Callable, f: Callable, x, y, batch_g, batch_f,
@@ -103,7 +113,9 @@ def fused_g_oracles(g: Callable, x, y, batch, u):
     def grads(xx, yy):
         return jax.grad(g, argnums=(0, 1))(xx, yy, batch)
 
-    (_, gy), (txy, tyy) = jax.jvp(grads, (x, y), (tree_zeros_like(x), u))
+    with annotate(G_SCOPE):
+        (_, gy), (txy, tyy) = jax.jvp(grads, (x, y),
+                                      (tree_zeros_like(x), u))
     return gy, txy, tyy
 
 
@@ -130,8 +142,9 @@ def fused_local_oracles(g: Callable, f: Callable, x, y, batch,
     def grads(xx, yy):
         return jax.grad(g, argnums=(0, 1))(xx, yy, batch)
 
-    (_, omega), (txy, _) = jax.jvp(grads, (x, y),
-                                   (tree_zeros_like(x), ihvp))
+    with annotate(G_SCOPE):
+        (_, omega), (txy, _) = jax.jvp(grads, (x, y),
+                                       (tree_zeros_like(x), ihvp))
     return omega, tree_sub(fx, txy)
 
 

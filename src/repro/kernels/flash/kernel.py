@@ -121,5 +121,6 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running denom l
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
+        name="flash_attention_bh",
         interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -69,5 +69,6 @@ def lru_scan_padded(a, b, h0, *, time_tile: int = TIME_TILE,
         out_specs=pl.BlockSpec((1, time_tile, cb), lambda bi, ci, ti: (bi, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, cb), jnp.float32)],
+        name="lru_scan_padded",
         interpret=resolve_interpret(interpret),
     )(a, b, h0)

@@ -120,6 +120,7 @@ def storm_update_flat(p, m, g_new, g_old, lr, decay, *,
         out_specs=[block, block],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
+        name="storm_update_flat",
         interpret=resolve_interpret(interpret),
     )(scal, p, m, g_new, g_old)
 
@@ -170,6 +171,7 @@ def storm3_update_flat(p, m, g_new, g_old, lrs, decays, *,
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
+        name="storm3_update_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(tile_scalars(lrs), tile_scalars(decays), p, m, g_new, g_old)
 
@@ -195,6 +197,7 @@ def storm3_step_flat(p, m, g_old, lrs, decays, *,
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
+        name="storm3_step_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(tile_scalars(lrs), tile_scalars(decays), p, m, g_old)
 
@@ -241,6 +244,7 @@ def sgd3_step_flat(p, g, lrs, *,
         in_specs=[TILE_SMEM, bspec, bspec],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct((n,), p.dtype),
+        name="sgd3_step_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(tile_scalars(lrs), p, g)
 
@@ -265,6 +269,7 @@ def momsgd3_step_flat(p, m, g, lrs, betas, *,
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
                    jax.ShapeDtypeStruct((n,), m.dtype)],
+        name="momsgd3_step_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(tile_scalars(lrs), tile_scalars(betas), p, m, g)
 

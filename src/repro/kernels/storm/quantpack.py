@@ -61,6 +61,7 @@ def quantpack_flat(x, *, block: int = BLOCK, interpret: bool | None = None):
         out_specs=[bspec, TILE_SMEM],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int8),
                    jax.ShapeDtypeStruct((n // block, 1, 1), jnp.float32)],
+        name="quantpack_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(x)
     return q, s.reshape(-1)
@@ -81,6 +82,7 @@ def quantunpack_flat(q, scales, *, block: int = BLOCK,
         in_specs=[TILE_SMEM, bspec],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        name="quantunpack_flat",
         interpret=_resolve_block_launch(interpret, block),
     )(tile_scalars(scales), q)
 

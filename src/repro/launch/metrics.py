@@ -29,10 +29,13 @@ from repro.telemetry.events import read_events
 def summarize(events: list) -> dict:
     by_type: dict = {}
     spans: dict = {}
+    span_counts: dict = {}
     for ev in events:
         by_type.setdefault(ev.get("event"), []).append(ev)
         if ev.get("event") == "span":
             spans[ev["name"]] = spans.get(ev["name"], 0.0) + ev["dur_s"]
+            span_counts[ev["name"]] = (span_counts.get(ev["name"], 0)
+                                       + ev.get("count", 1))
     losses = [(ev["step"], ev["val_loss"])
               for ev in by_type.get("metrics", ()) if "val_loss" in ev]
     comm = by_type.get("comm", ())
@@ -48,6 +51,7 @@ def summarize(events: list) -> dict:
                                         "clients_screened", ())
                                     for c in ev["clients"]}),
         "span_seconds": {k: round(v, 3) for k, v in sorted(spans.items())},
+        "span_counts": dict(sorted(span_counts.items())),
         "final_val_loss": losses[-1][1] if losses else None,
         "status": (by_type["run_end"][-1]["status"]
                    if by_type.get("run_end") else "(no run_end — live or "

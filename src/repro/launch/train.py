@@ -33,6 +33,7 @@ in a subprocess and auto-resumes from the latest checkpoint after a crash
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import subprocess
@@ -467,6 +468,16 @@ def main(argv=None):
                       else [round(float(x), 8) for x in a.reshape(-1)])
         return out
 
+    # host phases: with telemetry.trace on, each enters a profiler
+    # annotation and is summed in memory, one span event per phase at log
+    # steps; off, they cost nothing
+    if tracing:
+        from repro.telemetry import SpanTotals
+        spans = SpanTotals()
+        phase = spans.phase
+    else:
+        spans = None
+        phase = lambda name: contextlib.nullcontext()
     local_steps = exp.schedule.local_steps
     retry = lambda: guard.retries if guard is not None else 0
     t0 = time.time()  # analysis: ignore[L301] driver timing
@@ -474,7 +485,10 @@ def main(argv=None):
     t = start
     while t < exp.schedule.steps:
         key, sub = jax.random.split(key)
-        state, metrics = jstep(state, run.place_batch(run.batch_fn(sub)))
+        with phase("train/batch"):
+            batch = run.place_batch(run.batch_fn(sub))
+        with phase("train/dispatch"):
+            state, metrics = jstep(state, batch)
         t += 1
         is_comm = t % local_steps == 0
         is_log = t % ns.log_every == 0 or t == start + 1
@@ -516,11 +530,7 @@ def main(argv=None):
             if rb_ev is not None:
                 emit("comm", step=t, retry=retry(), **rb_ev)
         if is_log:
-            if tracing:
-                from repro.telemetry import phase
-                with phase("eval", log, step=t):
-                    l = run.eval_fn(state)
-            else:
+            with phase("eval"):
                 l = run.eval_fn(state)
             if guard is not None:
                 # host-copied snapshot: the live state's buffers are donated
@@ -566,18 +576,23 @@ def main(argv=None):
             # the RAW state (flat buffers included) + the embedded spec:
             # --resume rebuilds the structure from the spec alone.  The raw
             # key + retry count make resume exact across rollbacks.
-            save_checkpoint(
-                ns.ckpt_dir, state,
-                {"step": t, "arch": run.model_cfg.name,
-                 "key": np.asarray(key).tolist(),
-                 "retries": retry()},
-                experiment=exp)
+            with phase("checkpoint"):
+                save_checkpoint(
+                    ns.ckpt_dir, state,
+                    {"step": t, "arch": run.model_cfg.name,
+                     "key": np.asarray(key).tolist(),
+                     "retries": retry()},
+                    experiment=exp)
             emit("checkpoint", render=f"checkpoint @ step {t} -> "
                                       f"{ns.ckpt_dir}",
                  step=t, path=ns.ckpt_dir)
+        if spans is not None and is_log:
+            spans.emit(log, step=t)
         if ns.crash_at_step and start == 0 and t == ns.crash_at_step:
             print(f"crash-at-step: hard exit after step {t}", flush=True)
             os._exit(17)
+    if spans is not None:
+        spans.emit(log, step=t)
     if log is not None:
         log.emit("run_end", step=t, status="ok")
         log.close()

@@ -108,6 +108,7 @@ from repro.core.tree_util import (client_mean, client_mean_grouped,
                                   client_mean_grouped_weighted,
                                   client_mean_weighted)
 from repro.optim import flat
+from repro.telemetry.trace import annotate
 
 AVERAGED = "averaged"
 HIERARCHICAL = "hierarchical"
@@ -251,9 +252,7 @@ def comm_tree(cfg, step, tree, policy: str, *, weights=None,
     return lax.cond(is_comm, do_comm, lambda t: t, tree)
 
 
-def comm_buffers(spec: flat.FlatSpec, cfg, step, bufs, policies, *,
-                 weights=None, comm_every=None, shard=None,
-                 corrupt=None, robust=None, compress=None, ef=()):
+def comm_buffers(spec: flat.FlatSpec, cfg, step, bufs, policies, **kw):
     """Apply per-section policies to flat [M, N] buffers — one masked
     (sliced) reduction per communicated section run, private sections
     bit-identical (``flat.client_mean_masked``).
@@ -273,7 +272,16 @@ def comm_buffers(spec: flat.FlatSpec, cfg, step, bufs, policies, *,
     quantized/top-k sends and the call returns ``(bufs, ef)`` (the pair is
     threaded through every cadence cond); ``compress=None`` keeps the
     original single-value return and a bit-identical trajectory.
+
+    Everything it issues lies under the ``fed.reduce`` named scope.
     """
+    with annotate("fed.reduce"):
+        return _comm_buffers(spec, cfg, step, bufs, policies, **kw)
+
+
+def _comm_buffers(spec: flat.FlatSpec, cfg, step, bufs, policies, *,
+                  weights=None, comm_every=None, shard=None,
+                  corrupt=None, robust=None, compress=None, ef=()):
     assert all(p in POLICIES for p in policies), policies
     n = len(policies)
     ce = tuple(comm_every) if comm_every is not None else (1,) * n
@@ -659,9 +667,18 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
                 "sampling, faults= or robustness= — there is nothing to "
                 "screen")
 
-    def _flatten_grads(gdict):
-        return flat.flatten_tree(spec, {s: gdict[s] for s in sections},
-                                 batch_dims=1, dtype=jnp.float32)
+    def _oracle_flat(vars_bufs, batch, mask):
+        """The oracle at the iterate ``vars_bufs``, its gradients written
+        into flat f32 buffers with non-participants' rows zeroed; each
+        stage under its own named scope (profiler/HLO metadata only)."""
+        with annotate("fed.unflatten"):
+            views = flat.unflatten_tree(spec, vars_bufs)
+        with annotate("fed.oracle"):
+            gdict = oracle(views, batch)
+        with annotate("fed.flatten"):
+            g = flat.flatten_tree(spec, {s: gdict[s] for s in sections},
+                                  batch_dims=1, dtype=jnp.float32)
+            return flat.mask_buffers(g, mask)
 
     def _round_ctx(state: FlatState):
         """(mask, per-section comm weights, corrupt transform, staleness
@@ -863,13 +880,12 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
         #    entering iterate — lets the variable step and the partial
         #    momentum share a single fused launch); non-participants'
         #    contributions are zeroed (their oracle is "skipped")
-        g_old = flat.mask_buffers(
-            _flatten_grads(oracle(flat.unflatten_tree(spec, state.vars),
-                                  batch)), mask)
+        g_old = _oracle_flat(state.vars, batch, mask)
         # 2+3) partial momentum + variable step: ONE gated launch per dtype
-        vars_b, mom_b = flat.storm_partial_step(spec, state.vars, state.mom,
-                                                g_old, lrs, decays, mask=mask,
-                                                shard=shard)
+        with annotate("fed.update"):
+            vars_b, mom_b = flat.storm_partial_step(
+                spec, state.vars, state.mom, g_old, lrs, decays, mask=mask,
+                shard=shard)
         efv, efm = state.ef if state.ef else ((), ())
         # issue the variable-section reduction ...
         if ccfg is None:
@@ -886,10 +902,9 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
         #    add and the issued "data"-axis collective has no consumer until
         #    the step returns, so XLA can overlap it with oracle compute
         #    (documented deviation at comm rounds; identical elsewhere).
-        g_new = flat.mask_buffers(
-            _flatten_grads(oracle(flat.unflatten_tree(
-                spec, vars_b if overlap else vars_c), batch)), mask)
-        mom_b = flat.buffers_add(mom_b, g_new)
+        g_new = _oracle_flat(vars_b if overlap else vars_c, batch, mask)
+        with annotate("fed.update"):
+            mom_b = flat.buffers_add(mom_b, g_new)
         if ccfg is None:
             mom_b = comm_buffers(spec, cfg, t, mom_b, policies,
                                  weights=wts, comm_every=cadence, shard=shard,
@@ -910,15 +925,14 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
         t = state.step
         mask, wts, corrupt, stale_mask, s_info = _round_ctx(state)
         lrs = tuple(getattr(cfg, q.lr) for q in aspec.sequences)
-        g = flat.mask_buffers(
-            _flatten_grads(oracle(flat.unflatten_tree(spec, state.vars),
-                                  batch)), mask)
+        g = _oracle_flat(state.vars, batch, mask)
         efv, efm = state.ef if state.ef else ((), ())
         if has_mom:
             betas = (aspec.beta,) * len(aspec.sequences)
-            vars_b, mom_b = flat.momentum_sgd_step(spec, state.vars,
-                                                   state.mom, g, lrs, betas,
-                                                   mask=mask, shard=shard)
+            with annotate("fed.update"):
+                vars_b, mom_b = flat.momentum_sgd_step(
+                    spec, state.vars, state.mom, g, lrs, betas, mask=mask,
+                    shard=shard)
             if ccfg is None:
                 mom_b = comm_buffers(spec, cfg, t, mom_b, policies,
                                      weights=wts, comm_every=cadence,
@@ -930,8 +944,9 @@ def make_engine(cfg, aspec: AlgoSpec, templates: dict, oracle, *,
                                           shard=shard, compress=ccfg, ef=efm)
         else:
             # momentum-less: the plain-SGD launch (no dead momentum stream)
-            vars_b = flat.sgd_step(spec, state.vars, g, lrs, mask=mask,
-                                   shard=shard)
+            with annotate("fed.update"):
+                vars_b = flat.sgd_step(spec, state.vars, g, lrs, mask=mask,
+                                       shard=shard)
             mom_b = ()
         vars_local = vars_b         # pre-reduction local iterates (drift)
         if ccfg is None:

@@ -6,8 +6,9 @@ event stream of every run.
 * :class:`EventLog` — the schema-versioned, append-only JSONL event
   writer every driver emits to (``launch.train``, ``launch.dryrun``,
   ``benchmarks.run``);
-* :func:`phase` / :func:`annotate` — host wall-clock spans (wrapping
-  ``jax.profiler.TraceAnnotation``) and in-jit phase markers;
+* :func:`phase` / :class:`SpanTotals` / :func:`annotate` — host
+  wall-clock spans (wrapping ``jax.profiler.TraceAnnotation``), summed in
+  memory for a loop's per-step phases, and in-jit named scopes;
 * :func:`comm_plan` / :func:`round_bytes` — the per-round analytic
   communication-bytes model ``comm`` events carry;
 * ``python -m repro.telemetry.validate`` — schema validation + comm-bytes
@@ -24,12 +25,12 @@ from repro.telemetry.events import (EVENT_SCHEMA_VERSION, REQUIRED_KEYS,
                                     EventLog, TelemetryError, read_events)
 from repro.telemetry.spec import (METRIC_GROUPS, TelemetrySpec,
                                   resolve_metric_groups)
-from repro.telemetry.trace import annotate, measure_run, phase
+from repro.telemetry.trace import SpanTotals, annotate, measure_run, phase
 from repro.telemetry.validate import validate_events
 
 __all__ = [
     "CommPlan", "EVENT_SCHEMA_VERSION", "EventLog", "METRIC_GROUPS",
-    "REQUIRED_KEYS", "TelemetryError", "TelemetrySpec", "annotate",
-    "comm_plan", "measure_run", "phase", "read_events",
+    "REQUIRED_KEYS", "SpanTotals", "TelemetryError", "TelemetrySpec",
+    "annotate", "comm_plan", "measure_run", "phase", "read_events",
     "resolve_metric_groups", "round_bytes", "validate_events",
 ]

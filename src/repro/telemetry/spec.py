@@ -42,8 +42,9 @@ class TelemetrySpec(NamedTuple):
     (``events.jsonl`` next to the checkpoints, or in the cwd).
     ``metrics``: in-band metric groups, a subset of :data:`METRIC_GROUPS`;
     ``None`` = every applicable group, ``()`` = events only (no in-band
-    metrics).  ``trace``: emit wall-clock phase spans (host/batch, device
-    step, eval, checkpoint) as ``span`` events.
+    metrics).  ``trace``: time the host phases (``train/batch``,
+    ``train/dispatch``, ``eval``, ``checkpoint``) under profiler
+    annotations and emit their sums as ``span`` events at log steps.
     """
     sink: Optional[str] = None
     metrics: Optional[Tuple[str, ...]] = None

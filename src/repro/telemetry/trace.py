@@ -5,7 +5,9 @@ checkpoint writes, benchmark measurement windows) are wrapped in
 :func:`phase`: a context manager that enters a
 ``jax.profiler.TraceAnnotation`` (so the phase shows up in a profiler
 trace) and records the wall-clock span as a ``span`` event in the run's
-:class:`~repro.telemetry.events.EventLog`.  Device-side phases (oracle,
+:class:`~repro.telemetry.events.EventLog`; a loop's per-step phases go
+through :class:`SpanTotals`, which sums them in memory and writes one
+event per phase when asked.  Device-side phases (oracle,
 fused kernel, collective) are marked with :func:`annotate` —
 ``jax.named_scope`` — which attaches the phase name to the traced
 equations' metadata for profiler/HLO attribution without touching the
@@ -45,6 +47,34 @@ def phase(name: str, log=None, **fields):
             if log is not None:
                 log.emit("span", name=name,
                          dur_s=round(time.perf_counter() - t0, 6), **fields)  # analysis: ignore[L301] driver timing
+
+
+class SpanTotals:
+    """Host phases of a loop, kept in memory: :meth:`phase` is
+    :func:`phase` without the event, adding the span's wall-clock time and
+    one to its phase's count; :meth:`emit` writes one ``span`` event per
+    phase seen since the last call (``dur_s`` summed, ``count``) and starts
+    again.  A training loop pays no event write per step."""
+
+    def __init__(self):
+        self.totals: dict = {}
+
+    @contextmanager
+    def phase(self, name: str):
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()  # analysis: ignore[L301] host timing
+            try:
+                yield
+            finally:
+                dur = time.perf_counter() - t0  # analysis: ignore[L301] host timing
+                tot, n = self.totals.get(name, (0.0, 0))
+                self.totals[name] = (tot + dur, n + 1)
+
+    def emit(self, log, **fields) -> None:
+        for name, (dur, n) in self.totals.items():
+            log.emit("span", name=name, dur_s=round(dur, 6), count=n,
+                     **fields)
+        self.totals.clear()
 
 
 def _client_mean_loss(run, eval_batch):

@@ -70,6 +70,27 @@ def test_default_cache_dir_is_fixed_under_the_checkout():
         assert ".jax_cache/" in f.read().split()
 
 
+def test_entries_are_keyed_by_named_scopes(tmp_path):
+    """Two programs that differ only in a named scope get an entry each:
+    the scopes live only in the ops' metadata, which the profiler reads
+    from the compiled program, so neither may load the other's."""
+    name = f"probe_scope_{os.getpid()}"
+    code = textwrap.dedent(f"""
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        import jax, jax.numpy as jnp
+        def {name}(x):
+            with jax.named_scope(SCOPE):
+                return jnp.sin(x) * 3.0
+        jax.jit({name})(jnp.ones(3)).block_until_ready()
+        print("ok")
+    """)
+    for scope in ("fed.a", "fed.b", "fed.a"):
+        _run(f"SCOPE = {scope!r}" + code,
+             JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert len(_entries(str(tmp_path), name)) == 2
+
+
 @pytest.mark.timeout(300)
 def test_supervising_parent_holds_no_backend(tmp_path):
     """``train --max-restarts`` only starts children: the parent never

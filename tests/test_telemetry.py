@@ -253,3 +253,105 @@ def test_comm_plan_matches_flat_spec():
         plan.reductions * b1["elems"] * 4.0)
     # the x group extent covers the padded x run: elems >= 3*5, < 2 blocks
     assert 15 <= b1["elems"] <= 16
+
+
+@pytest.mark.timeout(600)
+def test_train_loop_emits_summed_host_spans(tmp_path):
+    """With telemetry.trace on, ``repro.launch.train`` sums its host phases in
+    memory and writes one ``span`` per phase at log steps (``dur_s``
+    summed, ``count``); ``repro.launch.metrics`` adds them up over the run."""
+    from repro.launch.metrics import summarize
+    root = os.path.join(os.path.dirname(__file__), "..")
+    sink = str(tmp_path / "events.jsonl")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"))
+    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    res = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--experiment",
+         os.path.join(root, "experiments", "fedbioacc_telemetry.json"),
+         "--telemetry-sink", sink, "--steps", "4", "--log-every", "2",
+         "--ckpt-dir", str(tmp_path / "ck"), "--ckpt-every", "2"],
+        env=env, capture_output=True, text=True, timeout=550)
+    assert res.returncode == 0, res.stderr[-2000:]
+    evs = read_events(sink)
+    spans = [e for e in evs if e["event"] == "span"]
+    # logs at steps 1, 2 and 4; checkpoints at 2 and 4
+    assert [e["step"] for e in spans] == [1] * 3 + [2] * 4 + [4] * 4
+    s = summarize(evs)
+    assert s["span_counts"] == {"checkpoint": 2, "eval": 3,
+                                "train/batch": 4, "train/dispatch": 4}
+    for name, total in s["span_seconds"].items():
+        assert total == pytest.approx(
+            sum(e["dur_s"] for e in spans if e["name"] == name), abs=1e-3)
+        assert total > 0
+
+
+SCOPES = ("fed.oracle", "fed.oracle_g", "fed.unflatten", "fed.flatten",
+          "fed.update", "fed.reduce")
+
+
+def _products_op_names(hlo_text):
+    """The full ``op_name`` of every ``dot``/``convolution`` of a lowered
+    (not yet optimized) module's HLO text with debug info.  An outlined
+    function's ops name themselves from the function down, so each is
+    prefixed with its call sites' names; loop and branch bodies name
+    themselves from their function already."""
+    import re
+    head = re.compile(r"^(ENTRY )?%?([\w.-]+) .*\{$")
+    bodies, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        m = head.match(line)
+        if m:
+            cur = m.group(2)
+            entry = cur if m.group(1) else entry
+            bodies[cur] = []
+        elif cur is not None and " = " in line:
+            bodies[cur].append(line)
+    out = []
+
+    def walk(comp, prefix):
+        for line in bodies[comp]:
+            op = re.search(r'op_name="([^"]*)"', line)
+            name = "/".join(filter(None, (prefix, op.group(1) if op else "")))
+            if re.search(r"\s(dot|convolution)\(", line):
+                out.append(name)
+            for callee in re.findall(r"to_apply=%?([\w.-]+)", line):
+                walk(callee, name)
+            for callee in re.findall(
+                    r"(?:body|condition|true_computation|false_computation)="
+                    r"%?([\w.-]+)", line):
+                walk(callee, prefix)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                for callee in re.findall(r"[\w.-]+", group):
+                    walk(callee, prefix)
+
+    walk(entry, "")
+    return out
+
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize("algo", ["fedbioacc", "fedbioacc_local"])
+def test_compiled_step_names_its_layers(algo):
+    """A tiny step compiled on the CPU carries every ``fed.`` scope in its
+    ops' metadata, as the benchmark's reader (``bench/scopes.py``) parses
+    it, and every matrix product the program issues lies under
+    ``fed.oracle``, in the lowered module and in the compiled one."""
+    from bench import scopes
+    from bench.trace import matmul_ops
+    from repro.api import Experiment, build
+    root = os.path.join(os.path.dirname(__file__), "..")
+    run = build(Experiment.load(os.path.join(root, "experiments",
+                                             f"{algo}.json")))
+    state = jax.eval_shape(run.init, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(run.batch_fn, jax.random.PRNGKey(1))
+    lowered = jax.jit(run.step).lower(state, batch)
+    after = lowered.compile().as_text()
+    compiled = scopes.scope_map(after)
+    assert set(SCOPES) <= {s for path in compiled.values() for s in path}
+    products = _products_op_names(
+        lowered.as_text(dialect="hlo", debug_info=True))
+    assert len(products) > 10
+    for name in products:
+        assert "fed.oracle" in scopes.scopes(name), name
+    for name in matmul_ops(after):
+        assert "fed.oracle" in compiled[name], (name, compiled[name])
