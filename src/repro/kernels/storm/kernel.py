@@ -34,13 +34,20 @@ Two kernel families live here:
   ``p' = p − lr·g`` (FedBiO / FedBiO-Local — 2 reads + 1 write, no dead
   momentum stream).
 
-Layout: inputs are flattened to [N] and tiled as (BLOCK,) VMEM blocks on a 1D
-grid. Scalars (lr, decay) arrive in SMEM: the single pair whole, the
-per-block tables one entry per grid step (:func:`tile_scalars` — a
-``[T, 1, 1]`` view blocked as ``(None, 1, 1)``), so SMEM holds a few words
-whatever the buffer size; a whole ``[T]`` table outgrows the 1 MiB SMEM of
-a TPU v5e at published widths with small blocks. ``interpret`` defaults to
-auto: Pallas interpreter everywhere except on a real TPU backend.
+Layout: ``storm_update_flat`` streams a flat [N] buffer as (BLOCK,) VMEM
+blocks on a 1D grid.  The triple-sequence kernels stream each buffer in its
+own layout: an [N] buffer the same way, an [R, N] buffer (R client rows, as
+the substrate holds them) as (R, block) blocks on a grid over the tiles —
+no reshape to [N] around the launch, which on a TPU relays the whole buffer
+between its 2-D tiling and the 1-D one.  Scalars (lr, decay) arrive in
+SMEM: the single pair whole, the per-block tables one tile per grid step
+(:func:`tile_scalars` — a ``[T, 1, 1]`` view blocked as ``(None, 1, 1)``,
+or ``[T, 1, R]`` blocked ``(None, 1, R)`` with one scalar per row, since
+the participation gate makes lr and decay differ per client), so SMEM
+holds a few words whatever the buffer size; a whole ``[T]`` table outgrows
+the 1 MiB SMEM of a TPU v5e at published widths with small blocks.
+``interpret`` defaults to auto: Pallas interpreter everywhere except on a
+real TPU backend.
 """
 from __future__ import annotations
 
@@ -82,9 +89,11 @@ TILE_SMEM = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0),
 
 
 def tile_scalars(table):
-    """Per-block f32 table [T] → the [T, 1, 1] view whose entries
-    :data:`TILE_SMEM` streams one per grid step (read as ``ref[0, 0]``)."""
-    return table.astype(jnp.float32).reshape(-1, 1, 1)
+    """Per-block f32 table [T] (or [T, R], one scalar per row) → the
+    [T, 1, 1] (or [T, 1, R]) view whose entries :data:`TILE_SMEM` (or a
+    ``(None, 1, R)`` block) streams one tile per grid step (read as
+    ``ref[0, r]``)."""
+    return table.astype(jnp.float32).reshape(table.shape[0], 1, -1)
 
 
 def _storm_kernel(scal_ref, p_ref, m_ref, gnew_ref, gold_ref,
@@ -129,10 +138,109 @@ def storm_update_flat(p, m, g_new, g_old, lr, decay, *,
 # Triple-sequence kernels (flat-buffer substrate)
 # ---------------------------------------------------------------------------
 
+#: VMEM that an [R, N] launch's double-buffered blocks may take within
+#: Mosaic's default scoped limit (16 MiB on a TPU v5e), leaving it room for
+#: its own scratch
+VMEM_BLOCKS = 12 * 2 ** 20
+
+#: rows of a row block, where an [R, N] launch steps over row blocks
+ROW_BLOCK = 8
+
+
+def _row_block(rows: int, block: int, itemsize: int):
+    """(rows per VMEM block, scoped VMEM limit or None) of an [R, N] launch
+    whose operands and outputs take ``itemsize`` bytes an element together.
+
+    A block's rows take VMEM rounded up to the buffer's row tile (1, 2, 4
+    or 8 rows: XLA tiles an [R, N] array in HBM that way, and the block
+    keeps the tiling).  All R rows while the double-buffered blocks fit
+    :data:`VMEM_BLOCKS`: rounded R·block ≤ 262,144 at the 24 B of an f32
+    ``storm3_update``, ≤ 393,216 at the 16 B of a bf16 ``storm3_step``, so
+    up to 4 rows of 64 Ki tiles for either.  Beyond that, row blocks of
+    :data:`ROW_BLOCK` where R divides by it (a block's second-minor dim is a
+    multiple of 8 or the whole dim), never a smaller tile: section
+    boundaries stay tile-aligned in N.  A launch whose blocks still exceed
+    the budget asks Mosaic for twice the VMEM they take: the body's f32
+    temporaries are block-sized too."""
+    tile = min(ROW_BLOCK, 1 << (rows - 1).bit_length())
+    need = lambda r: 2 * (-(-r // tile) * tile) * block * itemsize
+    if need(rows) > VMEM_BLOCKS and rows % ROW_BLOCK == 0:
+        rows = ROW_BLOCK
+    return rows, None if need(rows) <= VMEM_BLOCKS else 2 * need(rows)
+
+
+def _per_row(ref, shape):
+    """This tile's scalars of a [T, 1, R] SMEM table (blocked ``(None, 1,
+    R)``) for a block of ``shape``: the one scalar where R = 1, else a
+    select over the block's rows (the participation gate makes lr and
+    decay differ per client), offset by the row block in a stepped launch."""
+    rows = ref.shape[-1]
+    if rows == 1:
+        return ref[0, 0]
+    rb = shape[0]
+    base = 0 if rb == rows else pl.program_id(1) * rb
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    out = ref[0, base + rb - 1]
+    for r in reversed(range(rb - 1)):
+        out = jnp.where(row == r, ref[0, base + r], out)
+    return out
+
+
+def _stream(body, name: str, bufs, tables, n_out: int, block: int,
+            interpret):
+    """One ``pallas_call`` of ``body`` over same-shaped buffers, tile by
+    tile along their last axis, with one per-tile SMEM table per scalar.
+
+    ``bufs``: [N] or [R, N] with N a multiple of ``block``.  ``tables``:
+    [N // block] for [N] buffers, [N // block, R] (tile-major) for [R, N].
+    An [R, N] buffer is streamed in its own layout as (R, block) blocks on
+    a grid over the tiles, or over (tile, row block) where all R rows would
+    outgrow :data:`VMEM_BLOCKS`.  The first ``n_out`` buffers are updated
+    in place (each output aliases its input: a tile is read before it is
+    written, and XLA copies an input that is still live), so a donated
+    state buffer takes the new value with no copy beside the launch."""
+    shape = bufs[0].shape
+    assert len(shape) in (1, 2) and all(b.shape == shape for b in bufs), (
+        [b.shape for b in bufs])
+    n = shape[-1]
+    assert n % block == 0, (n, block)
+    tiles = n // block
+    assert all(t.shape == (tiles,) + shape[:-1] for t in tables), (
+        [t.shape for t in tables], shape, block)
+    vmem = None
+    if len(shape) == 1:
+        grid = (tiles,)
+        bspec = pl.BlockSpec((block,), lambda i: (i,))
+        tspec = TILE_SMEM
+    else:
+        rows = shape[0]
+        itemsize = sum(b.dtype.itemsize for b in bufs) + sum(
+            b.dtype.itemsize for b in bufs[:n_out])
+        rb, vmem = _row_block(rows, block, itemsize)
+        grid = (tiles, rows // rb)
+        bspec = pl.BlockSpec((rb, block), lambda i, k: (k, i))
+        tspec = pl.BlockSpec((None, 1, rows), lambda i, k: (i, 0, 0),
+                             memory_space=pltpu.SMEM)
+    outs = pl.pallas_call(
+        body,
+        grid=grid,
+        in_specs=[tspec] * len(tables) + [bspec] * len(bufs),
+        out_specs=[bspec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct(shape, b.dtype)
+                   for b in bufs[:n_out]],
+        name=name,
+        input_output_aliases={len(tables) + i: i for i in range(n_out)},
+        compiler_params=(None if vmem is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=vmem)),
+        interpret=_resolve_block_launch(interpret, block),
+    )(*[tile_scalars(t) for t in tables], *bufs)
+    return tuple(outs) if n_out > 1 else outs[0]
+
+
 def _storm3_kernel(lrs_ref, decays_ref, p_ref, m_ref, gnew_ref, gold_ref,
                    pout_ref, mout_ref):
-    lr = lrs_ref[0, 0]
-    decay = decays_ref[0, 0]
+    lr = _per_row(lrs_ref, m_ref.shape)
+    decay = _per_row(decays_ref, m_ref.shape)
     m = m_ref[...].astype(jnp.float32)
     g_new = gnew_ref[...].astype(jnp.float32)
     g_old = gold_ref[...].astype(jnp.float32)
@@ -142,8 +250,8 @@ def _storm3_kernel(lrs_ref, decays_ref, p_ref, m_ref, gnew_ref, gold_ref,
 
 def _storm3_step_kernel(lrs_ref, decays_ref, p_ref, m_ref, gold_ref,
                         pout_ref, mout_ref):
-    lr = lrs_ref[0, 0]
-    decay = decays_ref[0, 0]
+    lr = _per_row(lrs_ref, m_ref.shape)
+    decay = _per_row(decays_ref, m_ref.shape)
     m = m_ref[...].astype(jnp.float32)
     g_old = gold_ref[...].astype(jnp.float32)
     pout_ref[...] = (p_ref[...].astype(jnp.float32) - lr * m).astype(pout_ref.dtype)
@@ -155,25 +263,13 @@ def storm3_update_flat(p, m, g_new, g_old, lrs, decays, *,
                        block: int = BLOCK, interpret: bool | None = None):
     """Full triple-sequence fused STORM update on one flat buffer.
 
-    p, m, g_new, g_old: [N] with N a multiple of ``block``; segment
-    boundaries are block-aligned. lrs, decays: [N // block] per-block
-    hyper-parameters (constant within a segment), read from SMEM.
+    p, m, g_new, g_old: [N] or [R, N] with N a multiple of ``block``;
+    segment boundaries are block-aligned. lrs, decays: per-tile
+    hyper-parameters (constant within a segment), [N // block] or, per row,
+    [N // block, R]; read from SMEM.
     """
-    n = p.shape[0]
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    assert lrs.shape == decays.shape == grid, (lrs.shape, grid)
-    bspec = pl.BlockSpec((block,), lambda i: (i,))
-    return pl.pallas_call(
-        _storm3_kernel,
-        grid=grid,
-        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec, bspec],
-        out_specs=[bspec, bspec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        name="storm3_update_flat",
-        interpret=_resolve_block_launch(interpret, block),
-    )(tile_scalars(lrs), tile_scalars(decays), p, m, g_new, g_old)
+    return _stream(_storm3_kernel, "storm3_update_flat",
+                   (p, m, g_new, g_old), (lrs, decays), 2, block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -184,22 +280,10 @@ def storm3_step_flat(p, m, g_old, lrs, decays, *,
     This is the launch the real FedBiOAcc step uses — the new-iterate
     gradient is only available after communication, so the STORM correction
     ``m_part + g_new`` is completed by a single elementwise add later.
+    Shapes as in :func:`storm3_update_flat`.
     """
-    n = p.shape[0]
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    assert lrs.shape == decays.shape == grid, (lrs.shape, grid)
-    bspec = pl.BlockSpec((block,), lambda i: (i,))
-    return pl.pallas_call(
-        _storm3_step_kernel,
-        grid=grid,
-        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec],
-        out_specs=[bspec, bspec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        name="storm3_step_flat",
-        interpret=_resolve_block_launch(interpret, block),
-    )(tile_scalars(lrs), tile_scalars(decays), p, m, g_old)
+    return _stream(_storm3_step_kernel, "storm3_step_flat",
+                   (p, m, g_old), (lrs, decays), 2, block, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -214,15 +298,15 @@ def storm3_step_flat(p, m, g_old, lrs, decays, *,
 
 def _momsgd3_kernel(lrs_ref, betas_ref, p_ref, m_ref, g_ref,
                     pout_ref, mout_ref):
-    lr = lrs_ref[0, 0]
-    beta = betas_ref[0, 0]
+    lr = _per_row(lrs_ref, m_ref.shape)
+    beta = _per_row(betas_ref, m_ref.shape)
     m_new = beta * m_ref[...].astype(jnp.float32) + g_ref[...].astype(jnp.float32)
     pout_ref[...] = (p_ref[...].astype(jnp.float32) - lr * m_new).astype(pout_ref.dtype)
     mout_ref[...] = m_new.astype(mout_ref.dtype)
 
 
 def _sgd3_kernel(lrs_ref, p_ref, g_ref, pout_ref):
-    lr = lrs_ref[0, 0]
+    lr = _per_row(lrs_ref, g_ref.shape)
     pout_ref[...] = (p_ref[...].astype(jnp.float32)
                      - lr * g_ref[...].astype(jnp.float32)).astype(pout_ref.dtype)
 
@@ -232,21 +316,10 @@ def sgd3_step_flat(p, g, lrs, *,
                    block: int = BLOCK, interpret: bool | None = None):
     """Plain fused SGD step p_new = p − lr·g — the β = 0 fast path of the
     momentum-less specs (FedBiO / FedBiO-Local): 2 reads + 1 write per
-    element, no dead momentum output for the pallas_call to keep alive."""
-    n = p.shape[0]
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    assert lrs.shape == grid, (lrs.shape, grid)
-    bspec = pl.BlockSpec((block,), lambda i: (i,))
-    return pl.pallas_call(
-        _sgd3_kernel,
-        grid=grid,
-        in_specs=[TILE_SMEM, bspec, bspec],
-        out_specs=bspec,
-        out_shape=jax.ShapeDtypeStruct((n,), p.dtype),
-        name="sgd3_step_flat",
-        interpret=_resolve_block_launch(interpret, block),
-    )(tile_scalars(lrs), p, g)
+    element, no dead momentum output for the pallas_call to keep alive.
+    Shapes as in :func:`storm3_update_flat`."""
+    return _stream(_sgd3_kernel, "sgd3_step_flat", (p, g), (lrs,), 1,
+                   block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -254,24 +327,11 @@ def momsgd3_step_flat(p, m, g, lrs, betas, *,
                       block: int = BLOCK, interpret: bool | None = None):
     """Fused heavy-ball step: m_new = β·m + g ; p_new = p − lr·m_new.
 
-    Same layout contract as the storm3 kernels: flat [N] buffers with
-    block-aligned segment boundaries and per-block (lr, β) SMEM tables.
+    Same layout contract as the storm3 kernels: [N] or [R, N] buffers with
+    block-aligned segment boundaries and per-tile (lr, β) SMEM tables.
     """
-    n = p.shape[0]
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    assert lrs.shape == betas.shape == grid, (lrs.shape, grid)
-    bspec = pl.BlockSpec((block,), lambda i: (i,))
-    return pl.pallas_call(
-        _momsgd3_kernel,
-        grid=grid,
-        in_specs=[TILE_SMEM, TILE_SMEM, bspec, bspec, bspec],
-        out_specs=[bspec, bspec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        name="momsgd3_step_flat",
-        interpret=_resolve_block_launch(interpret, block),
-    )(tile_scalars(lrs), tile_scalars(betas), p, m, g)
+    return _stream(_momsgd3_kernel, "momsgd3_step_flat", (p, m, g),
+                   (lrs, betas), 2, block, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ section/shard quantum, so the very same tile boundaries exist on the
 unsharded buffer and on every ``shard_map`` chunk, and the quantization
 error is independent of how the buffer is partitioned.
 
-Layout contract (same as the storm3 kernels): flat [N] input with N a
+Layout contract (the storm3 kernels' 1-D one): flat [N] input with N a
 multiple of ``block``, 1-D grid of (block,) VMEM tiles.  ``quantpack_flat``
 emits the int8 payload plus the [N // block] scale vector; ``quantunpack_flat``
 consumes them.  Scales move one per grid step through SMEM in both

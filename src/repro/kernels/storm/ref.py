@@ -17,17 +17,25 @@ def storm_update_ref(p, m, g_new, g_old, lr, decay):
     return p_new, m_new
 
 
+def _per_element(table, block):
+    """Per-tile table [T] (or [T, R], one scalar per row) → per-element
+    [T·block] (or [R, T·block]), aligned with the buffers."""
+    t = jnp.asarray(table, jnp.float32)
+    return jnp.repeat(jnp.moveaxis(t, 0, -1), block, axis=-1)
+
+
 def storm3_update_ref(p, m, g_new, g_old, lrs, decays, block):
     """Triple-sequence reference: per-block (lr, decay) scalars expanded to
     per-element, then the same fp32 elementwise update as the kernel.
+    Buffers [N] with tables [N // block], or [R, N] with [N // block, R].
 
     Single source of the jnp math — kernel.py's ``storm3_*_flat_jnp``
     lowerings (the off-TPU production path) delegate here, so the Pallas
     kernel stays the only independent implementation and the
     kernel-vs-ref test sweeps remain meaningful.
     """
-    lr = jnp.repeat(jnp.asarray(lrs, jnp.float32), block)
-    decay = jnp.repeat(jnp.asarray(decays, jnp.float32), block)
+    lr = _per_element(lrs, block)
+    decay = _per_element(decays, block)
     m32 = m.astype(jnp.float32)
     p_new = (p.astype(jnp.float32) - lr * m32).astype(p.dtype)
     m_new = (g_new.astype(jnp.float32)
@@ -37,7 +45,7 @@ def storm3_update_ref(p, m, g_new, g_old, lrs, decays, block):
 
 def sgd3_step_ref(p, g, lrs, block):
     """Plain SGD reference: p_new = p − lr·g (fp32 accumulation)."""
-    lr = jnp.repeat(jnp.asarray(lrs, jnp.float32), block)
+    lr = _per_element(lrs, block)
     return (p.astype(jnp.float32) - lr * g.astype(jnp.float32)).astype(p.dtype)
 
 
@@ -48,8 +56,8 @@ def momsgd3_step_ref(p, m, g, lrs, betas, block):
         p_new = p − lr·m_new      (the *updated* momentum — FedAvg ordering;
                                    β = 0 degenerates to SGD: p − lr·g)
     """
-    lr = jnp.repeat(jnp.asarray(lrs, jnp.float32), block)
-    beta = jnp.repeat(jnp.asarray(betas, jnp.float32), block)
+    lr = _per_element(lrs, block)
+    beta = _per_element(betas, block)
     m_new = beta * m.astype(jnp.float32) + g.astype(jnp.float32)
     p_new = (p.astype(jnp.float32) - lr * m_new).astype(p.dtype)
     return p_new, m_new.astype(m.dtype)
@@ -58,8 +66,8 @@ def momsgd3_step_ref(p, m, g, lrs, betas, block):
 def storm3_step_ref(p, m, g_old, lrs, decays, block):
     """Half-step reference: p − lr·m and the partial momentum
     decay·(m − g_old) (the correction add happens post-communication)."""
-    lr = jnp.repeat(jnp.asarray(lrs, jnp.float32), block)
-    decay = jnp.repeat(jnp.asarray(decays, jnp.float32), block)
+    lr = _per_element(lrs, block)
+    decay = _per_element(decays, block)
     m32 = m.astype(jnp.float32)
     p_new = (p.astype(jnp.float32) - lr * m32).astype(p.dtype)
     m_part = (decay * (m32 - g_old.astype(jnp.float32))).astype(m.dtype)

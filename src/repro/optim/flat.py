@@ -19,7 +19,11 @@ Layout (``FlatSpec``):
   every tile belongs to exactly one section. ``_Group.section_ids`` maps
   tile → section; at step time the per-section (lr, decay) scalars are
   gathered into per-tile SMEM tables for the triple-sequence Pallas kernel
-  (``storm3_step_flat`` / ``storm3_update_flat``).
+  (``storm3_step_flat`` / ``storm3_update_flat``).  The kernel streams a
+  buffer in its own layout: an [N] buffer as (block,) VMEM blocks on a 1-D
+  grid, an [M, N] one as (M, block) blocks over the tiles with one scalar
+  per (tile, client) — never reshaped to 1-D, which on a TPU would relay
+  the whole buffer between the 2-D and the 1-D tiling around every launch.
 * Leaf offset metadata (``_Leaf``) makes flatten/unflatten pure
   reshape+concat / slice+reshape — no data-dependent work.
 * Buffers may carry leading batch dims (``batch_dims=1`` for the federated
@@ -309,28 +313,28 @@ def zeros_buffers(spec: FlatSpec, *, batch_shape: tuple = ()):
 # ---------------------------------------------------------------------------
 
 def _tile_table(grp: _Group, buf, table):
-    """Per-section scalar table → per-tile array [reps, T] for ``buf`` (the
-    section pattern repeats over any leading batch dims; ``reps`` is their
-    product).  Row-major flattening reproduces the kernel's client-major
-    SMEM layout; a 2-D P(data, model) sharding slices it consistently with
-    the buffer."""
-    reps = int(np.prod(buf.shape[:-1], dtype=np.int64)) if buf.ndim > 1 else 1
-    row = jnp.stack(table)[grp.section_ids]
-    return jnp.broadcast_to(row[None], (reps, row.shape[0]))
+    """Per-section scalar table → the per-tile table the kernel streams for
+    ``buf``: [T] for an [N] buffer, [T, M] (tile-major, one column per
+    client row) for an [M, N] one.  Under a P(data, model) sharding of the
+    buffer, P(model, data) slices it consistently with the buffer."""
+    col = jnp.stack(table)[grp.section_ids]
+    if buf.ndim == 1:
+        return col
+    return jnp.broadcast_to(col[:, None], (col.shape[0], buf.shape[0]))
 
 
 def _gate(lr_tiles, decay_tiles, mask, frozen_decay: float):
-    """Gate per-tile (lr, decay|β) tables [M, T] with the participation mask
+    """Gate per-tile (lr, decay|β) tables [T, M] with the participation mask
     [M]: non-participants get lr = 0 and decay pinned to ``frozen_decay``
     (1.0 freezes STORM/heavy-ball momenta bit-exact once their oracle
     contributions are zeroed)."""
     if mask is None:
         return lr_tiles, decay_tiles
-    assert mask.shape == (lr_tiles.shape[0],), (mask.shape, lr_tiles.shape)
-    col = mask.astype(jnp.float32)[:, None]
-    lr_tiles = lr_tiles * col
+    assert mask.shape == lr_tiles.shape[1:], (mask.shape, lr_tiles.shape)
+    row = mask.astype(jnp.float32)[None, :]
+    lr_tiles = lr_tiles * row
     if decay_tiles is not None:
-        decay_tiles = jnp.where(col > 0, decay_tiles,
+        decay_tiles = jnp.where(row > 0, decay_tiles,
                                 jnp.float32(frozen_decay))
     return lr_tiles, decay_tiles
 
@@ -379,34 +383,28 @@ def _check_shard(spec: FlatSpec, shard: ShardCtx, buf):
 
 def _launch(mode, flag, shard, spec, grp, kern_pallas, kern_jnp,
             bufs, tables, n_out: int):
-    """One fused kernel launch on one dtype buffer — flattened globally, or
-    per device chunk under ``shard_map`` when ``shard`` is given (every
-    device then streams its local [M/d, N/k] chunk with its slice of the
-    per-tile tables; the launch itself is collective-free)."""
+    """One fused kernel launch on one dtype buffer, in the buffer's own
+    layout: [N], or [M, N] streamed as (M, block) blocks with its [T, M]
+    tables — or per device chunk under ``shard_map`` when ``shard`` is given
+    (every device then streams its local [M/d, N/k] chunk with its [T/k,
+    M/d] slice of the tables; the launch itself is collective-free)."""
     if mode == "pallas":
         fn = functools.partial(kern_pallas, block=grp.block, interpret=flag)
     else:
         fn = functools.partial(kern_jnp, block=grp.block)
-    shape = bufs[0].shape
+
+    def body(*ops):
+        outs = fn(*ops)
+        return outs if n_out > 1 else (outs,)
+
     if shard is None:
-        outs = fn(*[b.reshape(-1) for b in bufs],
-                  *[t.reshape(-1) for t in tables])
-        outs = outs if n_out > 1 else (outs,)
-        return tuple(o.reshape(shape) for o in outs)
+        return body(*bufs, *tables)
 
     _check_shard(spec, shard, bufs[0])
     pb = shard.buffer_spec
-
-    def body(*ops):
-        bs, ts = ops[:len(bufs)], ops[len(bufs):]
-        lshape = bs[0].shape
-        outs = fn(*[b.reshape(-1) for b in bs],
-                  *[t.reshape(-1) for t in ts])
-        outs = outs if n_out > 1 else (outs,)
-        return tuple(o.reshape(lshape) for o in outs)
-
+    pt = PartitionSpec(shard.model_axis, shard.data_axis)
     return jax.shard_map(body, mesh=shard.mesh,
-                         in_specs=(pb,) * (len(bufs) + len(tables)),
+                         in_specs=(pb,) * len(bufs) + (pt,) * len(tables),
                          out_specs=(pb,) * n_out,
                          check_vma=False)(*bufs, *tables)
 

@@ -160,6 +160,126 @@ def test_compiled_kernel_refuses_block_below_tile(kernel):
     call(True)
 
 
+# The triple-sequence kernels stream an [R, N] buffer in its own layout as
+# (R, block) blocks with one scalar per (tile, row).  Each must give the
+# bits of the same kernel on the buffer reshaped to [R·N] (the launch it
+# replaces) and of its jnp lowering; a gated row (lr 0, decay|β 1, zeroed
+# gradients) must come out frozen bit-exact.
+
+RANK2_KERNELS = {
+    # name: (kernel, the operands after p, how many tables)
+    "storm3_step": ("storm3_step_flat", ("m", "g"), 2),
+    "storm3_update": ("storm3_update_flat", ("m", "g", "g"), 2),
+    "momsgd3": ("momsgd3_step_flat", ("m", "g"), 2),
+    "sgd3": ("sgd3_step_flat", ("g",), 1),
+}
+
+
+def _rank2_case(kernel, rows, block, data_tiles, gate, key):
+    """(kernel fn, jnp fn, [R, N] operands, [T, R] tables, frozen rows):
+    ``data_tiles`` tiles of random data and one zero padding tile; with
+    ``gate`` the last row is a non-participant's (lr 0, decay|β 1, zero
+    gradients), as ``flat._gate`` and ``flat.mask_buffers`` make it."""
+    from repro.kernels.storm import kernel as K
+    name, kinds, n_tables = RANK2_KERNELS[kernel]
+    tiles = data_tiles + 1
+    n = tiles * block
+    ks = jax.random.split(key, len(kinds) + 2)
+    live = (jnp.arange(n) < data_tiles * block)[None, :]
+    rand = lambda k, dt=jnp.float32: jnp.where(
+        live, jax.random.normal(k, (rows, n)), 0).astype(dt)
+    frozen = jnp.zeros((rows,), bool).at[-1].set(gate)
+    ops = [rand(ks[0], jnp.bfloat16)]
+    for k, kind in zip(ks[1:], kinds):
+        x = rand(k)
+        ops.append(jnp.where(frozen[:, None], 0.0, x) if kind == "g" else x)
+    lrs = jax.random.uniform(ks[-1], (tiles, rows), minval=0.01, maxval=0.5)
+    tables = [jnp.where(frozen[None, :], 0.0, lrs),
+              jnp.where(frozen[None, :], 1.0, 1.0 - lrs)][:n_tables]
+    return (getattr(K, name), getattr(K, name + "_jnp"), ops, tables,
+            np.asarray(frozen))
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])
+@pytest.mark.parametrize("kernel", sorted(RANK2_KERNELS))
+def test_rank2_kernel_matches_1d_launch_and_jnp(kernel, rows, rng):
+    block = 256
+    fn, fn_jnp, ops, tables, frozen = _rank2_case(
+        kernel, rows, block, 3, rows > 1, jax.random.fold_in(rng, rows))
+    outs = fn(*ops, *tables, block=block, interpret=True)
+    outs = outs if isinstance(outs, tuple) else (outs,)
+    flat_outs = fn(*[o.reshape(-1) for o in ops],
+                   *[t.T.reshape(-1) for t in tables],
+                   block=block, interpret=True)
+    flat_outs = flat_outs if isinstance(flat_outs, tuple) else (flat_outs,)
+    ref = fn_jnp(*ops, *tables, block=block)
+    ref = ref if isinstance(ref, tuple) else (ref,)
+    bits = lambda x: np.asarray(x).view(np.uint8)
+    for o, fo, r, src in zip(outs, flat_outs, ref, ops):
+        assert o.shape == src.shape and o.dtype == src.dtype
+        np.testing.assert_array_equal(bits(o), bits(fo.reshape(o.shape)))
+        np.testing.assert_array_equal(bits(o), bits(r))
+        np.testing.assert_array_equal(bits(o[:, -block:]),
+                                      bits(jnp.zeros_like(o[:, -block:])))
+        np.testing.assert_array_equal(bits(o[frozen]), bits(src[frozen]))
+    assert not np.array_equal(bits(outs[0]), bits(ops[0]))
+
+
+def test_rank2_kernel_steps_over_row_blocks(rng):
+    """16 rows of 64 Ki f32 tiles outgrow the VMEM budget: the launch steps
+    over row blocks of 8 and still gives the bits of one row at a time."""
+    from repro.kernels.storm import kernel as K
+    block, rows = 64 * 1024, 16
+    assert K._row_block(rows, block, 16)[0] == K.ROW_BLOCK
+    fn, _, ops, tables, frozen = _rank2_case(
+        "storm3_step", rows, block, 1, True, rng)
+    outs = fn(*ops, *tables, block=block, interpret=True)
+    for r in (0, 9, rows - 1):
+        one = fn(*[o[r] for o in ops], *[t[:, r] for t in tables],
+                 block=block, interpret=True)
+        for o, x in zip(outs, one):
+            np.testing.assert_array_equal(np.asarray(o[r]).view(np.uint8),
+                                          np.asarray(x).view(np.uint8))
+    np.testing.assert_array_equal(np.asarray(outs[1][-1]),
+                                  np.asarray(ops[1][-1]))
+
+
+def test_storm_partial_step_rank2_under_shard_ctx(rng):
+    """The substrate's launch on [M, N] buffers, gated: plain, under a
+    one-device ``shard_map`` and off the kernel (jnp) all agree bit for
+    bit, and the non-participant's rows stay frozen."""
+    import numpy as onp
+    from jax.sharding import Mesh
+    from repro.optim import flat
+    tree = {"x": {"w": jnp.zeros((40, 9)), "b": jnp.zeros((7,), jnp.bfloat16)},
+            "y": jnp.zeros((30,)), "u": jnp.zeros((30,))}
+    spec = flat.make_spec(tree, sections=("x", "y", "u"), block=128)
+    M = 3
+    ks = iter(jax.random.split(rng, 8))
+    rand = lambda dt: tuple(jax.random.normal(next(ks), (M, g.padded)).astype(
+        g.dtype if dt is None else dt) for g in spec.groups)
+    mask = jnp.asarray([1.0, 0.0, 1.0])
+    v, m = rand(None), rand(jnp.float32)
+    g = flat.mask_buffers(rand(jnp.float32), mask)
+    args = (spec, v, m, g, (0.1, 0.2, 0.3), (0.9, 0.8, 0.7))
+    ctx = flat.make_shard_ctx(
+        Mesh(onp.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model")))
+    plain = flat.storm_partial_step(*args, mask=mask, interpret=True)
+    sharded = flat.storm_partial_step(*args, mask=mask, interpret=True,
+                                      shard=ctx)
+    lowered = flat.storm_partial_step(*args, mask=mask)
+    for outs in (sharded, lowered):
+        for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(outs)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                          np.asarray(b).view(np.uint8))
+    (vn, mn) = plain
+    for a, b in zip(vn + mn, v + m):
+        np.testing.assert_array_equal(np.asarray(a[1]).view(np.uint8),
+                                      np.asarray(b[1]).view(np.uint8))
+        assert not np.array_equal(np.asarray(a[0]), np.asarray(b[0]))
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

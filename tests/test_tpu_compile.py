@@ -19,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -62,16 +63,44 @@ def shape(topo):
 
 
 @pytest.fixture(scope="module")
-def flat_n():
-    """(elements of the M-client parameter buffer, block) at published
-    widths — the bf16 group of the fedbioacc x|y|u layout."""
+def flat_spec():
+    """The fedbioacc x|y|u layout of ``mamba2-130m`` at published widths:
+    the bf16 group first, then one tile of f32 leaves."""
     model = build_model(get_config("mamba2-130m"), dtype=jnp.bfloat16)
     tmpl = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     spec = flat.make_spec({"x": tmpl["body"], "y": tmpl["head"],
                            "u": tmpl["head"]}, sections=("x", "y", "u"))
-    grp = spec.groups[0]
-    assert grp.dtype == jnp.bfloat16
+    assert spec.groups[0].dtype == jnp.bfloat16
+    return spec
+
+
+@pytest.fixture(scope="module")
+def flat_n(flat_spec):
+    """(elements of the M-client parameter buffer, block) at published
+    widths — the bf16 group of the fedbioacc x|y|u layout."""
+    grp = flat_spec.groups[0]
     return M * grp.padded, grp.block
+
+
+def _partial_step(spec):
+    """The real step's gated STORM launches on [M, N] client buffers, one
+    per dtype group: variables in the group's dtype, f32 momenta and
+    old-iterate gradients, a participation mask."""
+    k = len(spec.groups)
+
+    def step(*args):
+        v, m, g, (mask,) = (args[i * k:(i + 1) * k] for i in range(4))
+        return flat.storm_partial_step(spec, v, m, g, (0.02, 0.05, 0.05),
+                                       (0.9, 0.9, 0.9), mask=mask,
+                                       interpret=False)
+    return step
+
+
+def _client_buffers(shape, spec):
+    """(variables..., momenta..., gradients..., mask) for :func:`_partial_step`."""
+    v = [shape((M, g.padded), g.dtype) for g in spec.groups]
+    f32 = [shape((M, g.padded), jnp.float32) for g in spec.groups]
+    return (*v, *f32, *f32, shape((M,), jnp.float32))
 
 
 def _compiled_text(fn, *args):
@@ -91,10 +120,11 @@ def test_storm3_step_compiles(shape, flat_n):
         shape((n,), jnp.float32), t, t)
 
 
-def test_storm3_step_custom_call_keeps_its_name(shape, flat_n):
+def test_storm3_step_custom_call_keeps_its_name(shape, flat_n, flat_spec):
     """The benchmark finds the STORM launch in a profiler trace by its
     instruction's name, ``%storm3_step_flat*``: the kernel's ``name=``
-    holds under a caller's jit and named scope."""
+    holds under a caller's jit and named scope, for the 1-D launch and for
+    the substrate's launch on [M, N] client buffers."""
     import re
     from repro.kernels.storm.kernel import storm3_step_flat
     from repro.telemetry import annotate
@@ -106,13 +136,49 @@ def test_storm3_step_custom_call_keeps_its_name(shape, flat_n):
             return storm3_step_flat(p, m, g, lr, dc, block=block,
                                     interpret=False)
 
-    text = _compiled_text(train_step, shape((n,), jnp.bfloat16),
-                          shape((n,), jnp.float32), shape((n,), jnp.float32),
-                          t, t)
-    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .* custom-call\(",
-                       text, re.M)
-    assert calls and all(c.startswith("storm3_step_flat") for c in calls), (
-        calls)
+    def client_step(*args):
+        with annotate("fed.update"):
+            return _partial_step(flat_spec)(*args)
+
+    for text in (
+            _compiled_text(train_step, shape((n,), jnp.bfloat16),
+                           shape((n,), jnp.float32),
+                           shape((n,), jnp.float32), t, t),
+            _compiled_text(client_step, *_client_buffers(shape, flat_spec))):
+        calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .* custom-call\(",
+                           text, re.M)
+        assert calls and all(c.startswith("storm3_step_flat")
+                             for c in calls), calls
+
+
+def test_storm_partial_step_reads_client_rows_in_place(shape, flat_spec):
+    """At the real flat group (M=2 rows of 206 M bf16 elements) the gated
+    launch takes the [M, N] buffers in their own 2-D layout and updates the
+    donated ones in place: no loop, no buffer-sized copy or reshape around
+    the kernel, and no buffer-sized temporary (a reshape to 1-D relays every
+    buffer through the 1-D tiling, 4.13 GB of temporaries at this size)."""
+    import re
+    n = flat_spec.groups[0].padded
+    args = _client_buffers(shape, flat_spec)
+    # the train step donates its state, as here the variables and momenta
+    c = jax.jit(_partial_step(flat_spec),
+                donate_argnums=range(2 * len(flat_spec.groups))).lower(
+        *args).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\bwhile\(", text)
+    for dims, op in re.findall(
+            r"= \w+\[([\d,]*)\]\S* (copy|reshape)\(", text):
+        assert np.prod([int(d) for d in dims.split(",") if d]) < n, (dims, op)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == len(flat_spec.groups)
+    for grp in flat_spec.groups:
+        dt = "bf16" if grp.dtype == jnp.bfloat16 else "f32"
+        v, m = f"{dt}[{M},{grp.padded}]{{1,0", f"f32[{M},{grp.padded}]{{1,0"
+        # outputs and operands both in the buffers' own rank-2 layout
+        assert any(f"= ({v}" in ln and f", {m}" in ln and v + "}" in ln
+                   for ln in calls), (v, calls)
+    assert c.memory_analysis().temp_size_in_bytes < M * n * 2
 
 
 def test_storm3_step_small_block_fits_smem(shape, flat_n):
