@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """Readings that set a cell's limits (bench/limits/<cell>.json): the check's
 numbers for sound runs of the program, for the control and for each planted
-fault, over several seeds, in one process and at the cell's own size.
+fault, over several seeds, in one process and at the cell's own size, for
+whichever algorithm the cell's traffic names (its plain reference is
+``bench/reference/<algorithm>.py``, as in the benchmark's own runs).
 
     python bench/calibrate.py --workload <cell> --seeds 1-12 \
         [--control-seeds 1-3] [--operand-control-seeds 1-6] \

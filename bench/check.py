@@ -1,7 +1,8 @@
 """The output check: what the timed path produced against the plain
 reference, leaf by leaf.
 
-Three readings per side, each a 2-norm per leaf over all clients: the
+Three readings per side, each a 2-norm per leaf over all clients, of the
+sections and momenta the run's algorithm declares (:func:`sections`): the
 momenta after the first step (the first gradient as the optimizer holds
 it: ``c alpha_0^2`` times the oracle at the start), each section's change
 after the check's last step, and the momenta then.  A leaf's gap is
@@ -22,34 +23,46 @@ import jax.numpy as jnp
 
 from bench.reference.fedbioacc import leaf_norms
 
-#: momentum of each section in the program's state views
-MOMENTUM = {"x": "nu", "y": "omega", "u": "q"}
 #: a leaf whose reference gradient is below this share of the median leaf's
 #: is nought to rounding
 NOUGHT = 1e-3
 
 
-def sections(run, state):
-    """(variables, momenta) of ``state`` as {section: [M, ...] tree}."""
-    v = run.views(state)
-    return ({s: getattr(v, s) for s in MOMENTUM},
-            {s: getattr(v, m) for s, m in MOMENTUM.items()})
+def sections(run) -> list:
+    """``[(section, momentum, averaged)]`` of the run's algorithm, in the
+    order of its sequences: the names of the fields of ``run.views`` that
+    hold each variable section and its momentum, and whether the section
+    is ever averaged over clients (not private)."""
+    from repro.optim.sequences import PRIVATE, SPECS
+    return [(q.section, q.momentum, q.comm != PRIVATE)
+            for q in SPECS[run.spec.algorithm.name].sequences]
 
 
 def program_readers(run):
-    """Jitted readers of the program's state: ``first(state)`` the first
-    client's variables; ``grad1(state)`` and ``late(state, v0)`` the
-    per-leaf norms the check compares."""
+    """Jitted readers of the program's state: ``first(state)`` the start
+    the changes are measured from (an averaged section's first client
+    alone, since every client starts it the same; a private one's every
+    client); ``grad1(state)`` and ``late(state, v0)`` the per-leaf norms
+    the check compares."""
+    seqs = sections(run)
+
+    def split(state):
+        v = run.views(state)
+        return ({s: getattr(v, s) for s, _, _ in seqs},
+                {s: getattr(v, m) for s, m, _ in seqs})
+
     def first(state):
-        return jax.tree.map(lambda a: a[0], sections(run, state)[0])
+        v = split(state)[0]
+        return {s: jax.tree.map(lambda a: a[:1], v[s]) if shared else v[s]
+                for s, _, shared in seqs}
 
     def grad1(state):
-        return leaf_norms(sections(run, state)[1])
+        return leaf_norms(split(state)[1])
 
     def late(state, v0):
-        v, m = sections(run, state)
+        v, m = split(state)
         d = jax.tree.map(lambda a, b: a.astype(jnp.float32)
-                         - b.astype(jnp.float32)[None], v, v0)
+                         - b.astype(jnp.float32), v, v0)
         return {"change": leaf_norms(d), "mom": leaf_norms(m)}
 
     return jax.jit(first), jax.jit(grad1), jax.jit(late)
@@ -76,7 +89,7 @@ def left_out(ref: dict) -> list:
 
 def numbers(prog: dict, ref: dict) -> dict:
     """{number: (value, leaf)} of the program's readings against the
-    reference's (both as :func:`bench.reference.fedbioacc.run` returns):
+    reference's (both as :func:`bench.reference.fedbioacc.loop` returns):
     for each reading its worst leaf and its median leaf."""
     keep = set(ref["grad1"]) - set(left_out(ref))
     out = {}

@@ -6,8 +6,10 @@ long as the context, so they are in place when the step is traced.
 * ``unchanged`` — the step returns its state as it got it;
 * ``half_batch`` — the second half of every row's labels is masked out, so
   the loss is the mean over the first half;
-* ``no_exchange`` — the client reduction returns each client's own value;
-* ``answer_altered`` — the oracle's lower gradient comes out doubled.
+* ``no_exchange`` — the client reduction returns each client's own value
+  (only the averaged sections ever enter it);
+* ``answer_altered`` — the oracle's lower gradient comes out doubled, from
+  whichever fused oracle the algorithm runs (global or local lower level).
 """
 from __future__ import annotations
 
@@ -49,15 +51,20 @@ def planted(name: str):
         finally:
             sequences.comm_buffers = orig
     elif name == "answer_altered":
-        orig = hypergrad.fused_oracles
+        origs = {k: getattr(hypergrad, k)
+                 for k in ("fused_oracles", "fused_local_oracles")}
 
-        def doubled(*args):
-            omega, mu, p = orig(*args)
-            return jax.tree.map(lambda a: 2 * a, omega), mu, p
-        hypergrad.fused_oracles = doubled
+        def doubled(orig):
+            def fn(*args):
+                omega, *rest = orig(*args)
+                return (jax.tree.map(lambda a: 2 * a, omega), *rest)
+            return fn
+        for k, orig in origs.items():
+            setattr(hypergrad, k, doubled(orig))
         try:
             yield build
         finally:
-            hypergrad.fused_oracles = orig
+            for k, orig in origs.items():
+                setattr(hypergrad, k, orig)
     else:
         raise ValueError(f"unknown fault {name!r}; choose from {FAULTS}")

@@ -22,8 +22,14 @@ for, it exits non-zero before any work and prints no result.
 
 Per-layer metrics are read by ``bench/layers/<metric>.py``:
 ``read(trace, ctx)`` of a :class:`bench.trace.Trace` and a dict with
-``steps``, ``chips``, ``flops_per_step``, ``storm_bytes_per_step`` and the
-chip's ``peak``; ``None`` leaves the metric out of the line.
+``steps``, ``chips``, ``flops_per_step``, ``storm_bytes_per_step``, the
+chip's ``peak``, the compiled step's HLO text (``hlo``) and its matrix ops
+(``matmul_ops``); ``None`` leaves the metric out of the line.
+
+The output check and the counts follow the traffic's job: the plain
+reference is ``bench/reference/<algorithm>.py``, the check compares the
+sections that algorithm declares, and tokens and FLOPs count only the
+clients whose work a step requires (:func:`bench.traffic.participants`).
 """
 from __future__ import annotations
 
@@ -85,14 +91,37 @@ def param_dtype(config: dict):
     return jnp.dtype(config["param_dtype"])
 
 
+def algorithm(traffic: dict) -> str:
+    """The name of the federated algorithm the traffic's job runs."""
+    return traffic["experiment"]["algorithm"]["name"]
+
+
 def schedule(traffic: dict) -> dict:
-    """The hyper-parameters the plain FedBiOAcc loop needs, as the traffic
-    file's job states them."""
+    """The hyper-parameters the plain references need, as the traffic
+    file's job states them: the schedule's, the algorithm's own and the
+    participation."""
     job = traffic["experiment"]
     sch = job["schedule"]
     return {**{k: sch[k] for k in ("lr_x", "lr_y", "lr_u", "local_steps",
-                                   "lower_l2")},
-            **job["algorithm"]["params"]}
+                                   "lower_l2", "neumann_q", "neumann_tau")},
+            **job["algorithm"]["params"],
+            "participation": job["participation"]}
+
+
+def flops_per_step(cell) -> float:
+    """Required FLOPs of one step: ``forward_units`` forward passes
+    (``bench/flops/<family>.py``) over every participant's tokens, plus,
+    where the algorithm's oracle does more, its own count
+    (``bench/flops/<algorithm>.py``)."""
+    from bench import traffic
+    tr, sizes = cell.traffic, cell.config["sizes"]
+    per_token = tr["forward_units"] * family(cell.config)[1].flops_per_token(
+        sizes, tr["seq_len"])
+    if os.path.isfile(os.path.join(HERE, "flops", algorithm(tr) + ".py")):
+        per_token += _module("flops", algorithm(tr)).flops_per_token(
+            sizes, schedule(tr))
+    return per_token * traffic.participants(tr) * tr["per_client"] \
+        * tr["seq_len"]
 
 
 def keys(seed: int):
@@ -132,18 +161,17 @@ def check_steps(run, jstep, state, feed, n: int):
 
 
 def reference(cell, batch, seed: int, ar=None):
-    """The plain reference's readings over the check's first steps, from
-    the same seed and batches."""
-    import jax
-    from bench.reference import common, fedbioacc
-    fam, _ = family(cell.config)
-    sizes = cell.config["sizes"]
-    params = jax.jit(lambda k: fam.init(k, sizes, param_dtype(cell.config)))(
-        keys(seed)[0])
+    """The readings of the traffic's algorithm's plain reference
+    (``bench/reference/<algorithm>.py``) over the check's first steps,
+    from the same seed and batches."""
+    from bench.reference import common
+    alg = importlib.import_module(
+        f"bench.reference.{algorithm(cell.traffic)}")
     n = cell.traffic["check_steps"]
-    return fedbioacc.run(fam, sizes, params, [batch(i) for i in range(n)],
-                         schedule(cell.traffic), cell.traffic["clients"],
-                         ar or common.Arith())
+    return alg.run(family(cell.config)[0], cell.config["sizes"],
+                   param_dtype(cell.config), keys(seed)[0],
+                   [batch(i) for i in range(n)], schedule(cell.traffic),
+                   cell.traffic["clients"], ar or common.Arith())
 
 
 def nonfinite_leaves(tree) -> int:
@@ -163,7 +191,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, pk,
     from bench import check, storm_bytes, traffic
     from bench.window import Window
 
-    fam, flops = family(cell.config)
+    fam, _ = family(cell.config)
     tr, sizes = cell.traffic, cell.config["sizes"]
     log("imports", at_s=time.perf_counter() - T0)
     run = build_fn(cell)
@@ -214,9 +242,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, pk,
     correct = bad == 0 and all(v <= limits[k] for k, (v, _) in nums.items())
 
     dev = devices[0]
-    flops_step = (tr["forward_units"]
-                  * flops.flops_per_token(sizes, tr["seq_len"])
-                  * tr["clients"] * tr["per_client"] * tr["seq_len"])
+    flops_step = flops_per_step(cell)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
     out = {"correct": correct, "attempted": n, "failed": n if bad else 0}
@@ -245,9 +271,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, pk,
             k, sizes, param_dtype(cell.config)), kw)
         ctx = {"steps": n, "chips": len(devices), "peak": pk,
                "flops_per_step": flops_step,
-               "matmul_ops": trace_mod.matmul_ops(hlo),
+               "matmul_ops": trace_mod.matmul_ops(hlo), "hlo": hlo,
                "storm_bytes_per_step": storm_bytes.bytes_per_step(
-                   params, tr["clients"])}
+                   params, tr["clients"],
+                   [s for s, _, _ in check.sections(run)])}
         out["metrics"] = {}
         for m in cell.metrics["per_layer"]:
             v = _module("layers", m["name"]).read(t, ctx)

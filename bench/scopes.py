@@ -21,10 +21,9 @@ per step of the ops a predicate of their scopes selects; the ops that only
 hold other ops (``while``, ``conditional``, ``call``) are never counted.
 :data:`METRICS` names the per-layer quantities the scopes give.
 
-No metric of ``BENCHMARK.json`` reads them yet: the per-layer readers'
-context (``bench/run.py``) carries the compiled step's matrix ops, not its
-HLO text.  Given that text, a reader ``bench/layers/<name>.py`` returns
-``ms(trace, ctx.get("hlo"), METRICS[name])``.
+The per-layer readers' context (``bench/run.py``) carries the compiled
+step's HLO text, and the reader ``bench/layers/<name>.py`` of each of
+:data:`METRICS` returns ``ms(trace, ctx.get("hlo"), METRICS[name])``.
 """
 from __future__ import annotations
 
@@ -115,14 +114,16 @@ def scope_map(hlo_text: str) -> dict:
 #: per-layer quantities, each the selection of an op's scopes that
 #: :func:`ms` sums: the hypergradient oracle at both STORM points; every
 #: differentiation of g; the flatten/unflatten boundary (the outermost
-#: scope, so an oracle inside it would not count); the client reduction,
-#: averaged over the steps that communicate and those that do not; and
-#: what the scopes miss
+#: scope, so an oracle inside it would not count); the update; the client
+#: reduction, averaged over the steps that communicate and those that do
+#: not; and what the scopes miss.  All but ``oracle.g_ms`` (part of the
+#: oracle) add up to the step's busy time
 METRICS = {
     "oracle.ms": lambda path: "fed.oracle" in path,
     "oracle.g_ms": lambda path: "fed.oracle_g" in path,
     "flat.boundary_ms": lambda path: top(path) in ("fed.unflatten",
                                                    "fed.flatten"),
+    "update.ms": lambda path: "fed.update" in path,
     "reduce.ms": lambda path: "fed.reduce" in path,
     "step.unscoped_ms": lambda path: not path,
 }

@@ -28,3 +28,13 @@ def test_moe_hand_count():
     # of 3 matrices 8 x 6: 2 x 3 x 2 x 48 = 576; per layer 1168, three
     # layers 3504, head 160
     assert _module("flops", "moe").flops_per_token(MOE, 7) == 3664
+
+
+def test_local_oracle_hand_count():
+    # Q = 2 products with the head's Hessian at each of two STORM points,
+    # each two head products of 2 x 8 x 10 = 160: 2 x 2 x 2 x 160 = 1280
+    count = _module("flops", "fedbioacc_local").flops_per_token(
+        SSM, {"neumann_q": 2, "neumann_tau": 0.5})
+    assert count == 1280
+    assert _module("flops", "fedbioacc_local").flops_per_token(
+        SSM, {"neumann_q": 0}) == 0

@@ -132,6 +132,7 @@ def test_readers_sum_device_time_by_scope():
     assert read_("oracle.ms") == pytest.approx((20 + 30 + 6) / 2 / 1e6)
     assert read_("oracle.g_ms") == pytest.approx(30 / 2 / 1e6)
     assert read_("flat.boundary_ms") == pytest.approx((10 + 5) / 2 / 1e6)
+    assert read_("update.ms") == pytest.approx(15 / 2 / 1e6)
     assert read_("reduce.ms") == pytest.approx((8 + 2) / 2 / 1e6)
     # the copy, the add and an op the HLO does not name
     assert read_("step.unscoped_ms") == pytest.approx((4 + 2 + 2) / 2 / 1e6)
@@ -147,11 +148,17 @@ def test_top_level_buckets_partition_the_busy_time():
     assert S.ms(t, HLO, lambda p: True) == pytest.approx(total)
 
 
+def test_metrics_but_the_g_share_add_up_to_the_scoped_time():
+    t = T.Trace(synthetic(), steps=2)
+    total = sum(read(name, t) for name in S.METRICS if name != "oracle.g_ms")
+    assert total == pytest.approx(S.ms(t, HLO, lambda p: True))
+
+
 def test_readers_are_silent_without_scopes():
     t = T.Trace(synthetic(), steps=2)
     bare = HLO.replace("fed.", "x")
     assert set(S.METRICS) == {"oracle.ms", "oracle.g_ms", "flat.boundary_ms",
-                              "reduce.ms", "step.unscoped_ms"}
+                              "update.ms", "reduce.ms", "step.unscoped_ms"}
     for name in S.METRICS:
         assert read(name, t, None) is None
         assert read(name, t, bare) is None
@@ -180,7 +187,7 @@ def test_recorded_step_by_scope():
     ops = {o.name: o for o in launch["devices"]["/device:TPU:0"]}
     dur = lambda *names: sum(o.dur for o in launch["devices"]["/device:TPU:0"]
                              if o.name in names) / 1e6
-    assert S.ms(t, hlo, lambda p: S.top(p) == "fed.update") == pytest.approx(
+    assert read_("update.ms") == pytest.approx(
         ops["%storm3_step_flat.2"].dur / 1e6)
     assert read_("reduce.ms") == pytest.approx(dur(
         "%convert_reduce_fusion", "%multiply_convert_fusion", "%copy-start.208"))

@@ -52,6 +52,21 @@ def make_batch_fn(traffic: dict, vocab: int, key):
     return batch
 
 
+def participants(traffic: dict) -> int:
+    """Clients whose work a step requires: every client under the job's
+    ``full`` sampler, ``clients_per_round`` of them under ``uniform``.  The
+    program may run an oracle on every client's rows and discard the
+    others'; that work is not required."""
+    p = traffic["experiment"]["participation"]
+    if p["sampler"] == "full":
+        return traffic["clients"]
+    if p["sampler"] == "uniform":
+        return p["clients_per_round"] or traffic["clients"]
+    raise SystemExit(f"bench: no count of participants for the "
+                     f"{p['sampler']!r} sampler")
+
+
 def tokens_per_step(traffic: dict) -> int:
-    """Tokens one step consumes: both streams of every client."""
-    return 2 * traffic["clients"] * traffic["per_client"] * traffic["seq_len"]
+    """Tokens one step requires: both streams of every participant."""
+    return 2 * participants(traffic) * traffic["per_client"] \
+        * traffic["seq_len"]
